@@ -36,8 +36,8 @@ def backend_scope(backend: str | None):
 def resolve_backend(backend: str | None) -> tuple[str, str]:
     """Map a --backend flag to (effective backend, row-name tag).
 
-    The effective backend is what will actually execute ("pallas" degrades
-    to "interpret" off-TPU); the tag is the `-<effective>` row-name suffix
+    The effective backend is what will actually execute ("pallas" raises
+    off-TPU rather than run anything else); the tag is the `-<effective>` row-name suffix
     the fig benchmarks append.  The ambient selection (no flag) stays
     untagged EXCEPT when it resolves to interpret: interpret runs shrink
     the benchmark scale, and rows from a shrunken run must never share a

@@ -9,11 +9,11 @@ Backend selection (the fused propagation-round kernel):
 
     PYTHONPATH=src python benchmarks/fig5_construction.py --backend pallas
 
-records the fused-kernel construction path.  Off-TPU, "pallas" degrades
-to interpret mode (Python-stepped kernels), which is a CORRECTNESS
-harness, not a performance mode — the benchmark shrinks the dataset so
-the end-to-end run stays tractable, and the row is labeled with the
-effective backend.  The numbers that matter for the fused path on real
+records the fused-kernel construction path; it needs a TPU.  Off-TPU,
+`--backend interpret` runs the same kernels Python-stepped, which is a
+CORRECTNESS harness, not a performance mode — the benchmark shrinks the
+dataset so the end-to-end run stays tractable, and the row is labeled
+with the effective backend.  The numbers that matter for the fused path on real
 hardware come from the analytic roofline (benchmarks/roofline.py) and
 from a TPU run of this same flag.  See EXPERIMENTS.md §Perf cell F.
 """

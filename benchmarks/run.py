@@ -24,6 +24,8 @@ import re
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 ALL = ("fig5", "fig6", "fig7", "fig8", "fig9", "fig10", "fig11", "fig12",
        "fig13", "fig14", "fig15", "fig16", "roofline")
 
@@ -319,6 +321,7 @@ def main() -> None:
                          "+ family completeness) and exit; the CI gate "
                          "step (runs nothing)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check:
         if args.smoke or args.only:
@@ -333,18 +336,23 @@ def main() -> None:
 
     which = args.only or ALL
     print("name,us_per_call,derived")
+    failed = []
     for name in which:
         t0 = time.time()
         m = _module(name)
         if m is None:
             print(f"# unknown benchmark {name}", file=sys.stderr)
+            failed.append(name)
             continue
         try:
             for row in m.run():
                 print(row, flush=True)
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # run the other families, then fail
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"# benchmark families failed: {failed}")
 
 
 if __name__ == "__main__":

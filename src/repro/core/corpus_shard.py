@@ -70,7 +70,7 @@ from repro.core import vecstore as VS
 from repro.core.grnnd import GRNNDConfig, build_graph, reverse_edge_round
 from repro.core.search import (
     SearchResult, _rescore_merge, _table_insert, _table_member,
-    default_visited_cap, medoid)
+    align_queries, default_visited_cap, medoid, unpad)
 from repro.kernels import ops
 
 __all__ = [
@@ -530,6 +530,7 @@ def sharded_search(
     else:
         cap = (visited_cap if visited_cap is not None
                else default_visited_cap(ef))
+    queries, fwords, qn = align_queries(queries, fwords)
     host = VS.is_host(index.rescores)
     if host:
         # host-cold tier (DESIGN.md §13): traversal runs without the
@@ -559,13 +560,13 @@ def sharded_search(
             visited=visited, visited_cap=cap,
             backend=ops.effective_backend())
     if not host:
-        return res
+        return unpad(res, qn)
     rv = index.rescores.gather(res.ids)                    # (Q, ef, D)
     flat_map = (None if index.ids_maps is None
                 else index.ids_maps.reshape(-1))
     out_ids, out_dists = _rescore_merge(
         res.ids, rv, jnp.asarray(queries, jnp.float32), flat_map, k=k)
-    return SearchResult(out_ids, out_dists, res.n_expanded)
+    return unpad(SearchResult(out_ids, out_dists, res.n_expanded), qn)
 
 
 # ---------------------------------------------------------------------------

@@ -48,14 +48,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
-from repro.compat import shard_map
 
 from repro.core import labels as L
 from repro.core import pools as P
 from repro.core import vecstore as VS
 from repro.core.grnnd import (
-    GRNNDConfig, _pair_requests_chunk, _sorted_requests_chunk)
-from repro.core.search import SearchResult, _rescore_merge, medoid, search
+    GRNNDConfig, _pair_requests_chunk, _reverse_requests,
+    _sorted_requests_chunk)
+from repro.core.search import (
+    SearchResult, _rescore_merge, align_queries, medoid, search, unpad)
 from repro.kernels import ops
 
 
@@ -93,11 +94,17 @@ def make_sharded_builder(
     cfg: GRNNDConfig,
     comm: str = "allgather",
 ):
-    """Returns jit-able build_round(x, pool, key) with pools vertex-sharded.
+    """Returns jit-able build_round(x, pool, key, reverse=False) with
+    pools vertex-sharded.
 
     `axes` are the mesh axis names carrying the vertex shard (e.g.
-    ("data",) or ("pod", "data")).  `comm` selects the redirect exchange:
-    "allgather" (exact) or "a2a" (bucketed all_to_all, bounded payload).
+    ("data",) or ("pod", "data")).  `comm` selects the update rounds'
+    redirect exchange: "allgather" (exact) or "a2a" (bucketed all_to_all,
+    bounded payload, drops a bucket's overflow).  `reverse` (traced) makes
+    the round a reverse-edge round (§3.6): the same staging and merge, fed
+    with reverse requests and no kills, always through the exact
+    all-gather exchange — one compiled program serves both kinds of
+    round, and the staging sorts dominate its compile time on a TPU.
     """
     axes = tuple(axes)
     vspec = PSpec(axes)          # vertex-sharded arrays
@@ -113,74 +120,107 @@ def make_sharded_builder(
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
         return idx
 
-    def round_body(x, ids_loc, dists_loc, key):
+    def round_body(x, ids_loc, dists_loc, key, reverse):
         n_loc, r = ids_loc.shape
         sidx = shard_index()
         row0 = sidx * n_loc
         key = jax.random.fold_in(key, sidx)
+        m = n_loc * max(r, cfg.pairs_per_vertex)
 
-        redirect, killed = _local_round_requests(
-            x, ids_loc, dists_loc, row0, key, cfg)
+        def pad(req):  # inactive tail: both branches emit m requests
+            k = m - req.dst.shape[0]
+            return P.Requests(jnp.pad(req.dst, (0, k), constant_values=-1),
+                              jnp.pad(req.src, (0, k), constant_values=-1),
+                              jnp.pad(req.dist, (0, k),
+                                      constant_values=jnp.inf))
 
-        if comm == "allgather":
-            red_all = P.Requests(
-                dst=jax.lax.all_gather(redirect.dst, axes, tiled=True),
-                src=jax.lax.all_gather(redirect.src, axes, tiled=True),
-                dist=jax.lax.all_gather(redirect.dist, axes, tiled=True),
+        def update(_):
+            req, killed = _local_round_requests(
+                x, ids_loc, dists_loc, row0, key, cfg)
+            return pad(req), killed
+
+        def reverse_edges(_):
+            dst, src, dist = _reverse_requests(P.Pool(ids_loc, dists_loc),
+                                               cfg.rho)
+            req = P.Requests(dst.reshape(-1), (src + row0).reshape(-1),
+                             dist.reshape(-1))
+            return pad(req), jnp.zeros((n_loc, r), bool)
+
+        redirect, killed = jax.lax.cond(reverse, reverse_edges, update, None)
+
+        def gather_all(red):
+            return P.Requests(
+                dst=jax.lax.all_gather(red.dst, axes, tiled=True),
+                src=jax.lax.all_gather(red.src, axes, tiled=True),
+                dist=jax.lax.all_gather(red.dist, axes, tiled=True),
             )
-        else:  # bucketed all_to_all: fixed cap per (src shard, dst shard)
-            # expected redirects/bucket ≈ n_loc · pairs / n_shards; 2x slack.
+
+        def bucket_a2a(red):
+            # fixed cap per (src shard, dst shard), sized for update rounds:
+            # expected redirects/bucket ≈ n_loc · pairs / n_shards; 2x slack
             cap = max(2 * n_loc * cfg.pairs_per_vertex // max(n_shards, 1), r)
-            dst_shard = jnp.where(
-                redirect.dst >= 0, redirect.dst // n_loc, n_shards)
+            dst_shard = jnp.where(red.dst >= 0, red.dst // n_loc, n_shards)
             buckets_i = jnp.full((n_shards, cap), -1, jnp.int32)
             buckets_s = jnp.full((n_shards, cap), -1, jnp.int32)
             buckets_d = jnp.full((n_shards, cap), jnp.inf, jnp.float32)
             order = jnp.argsort(dst_shard, stable=True)
             ds = dst_shard[order]
             idx = jnp.arange(ds.shape[0], dtype=jnp.int32)
-            is_start = jnp.concatenate([jnp.array([True]), ds[1:] != ds[:-1]])
-            seg0 = jax.lax.associative_scan(
-                jnp.maximum, jnp.where(is_start, idx, 0))
-            rank = idx - seg0
+            # rank within the shard's run (no prefix scan: see pools._stage)
+            first = jnp.full((n_shards + 1,), ds.shape[0],
+                             jnp.int32).at[ds].min(idx)
+            rank = idx - first[ds]
             okk = (rank < cap) & (ds < n_shards)
             row = jnp.where(okk, ds, n_shards)
-            buckets_i = buckets_i.at[row, rank].set(
-                redirect.dst[order], mode="drop")
-            buckets_s = buckets_s.at[row, rank].set(
-                redirect.src[order], mode="drop")
-            buckets_d = buckets_d.at[row, rank].set(
-                redirect.dist[order], mode="drop")
+            buckets_i = buckets_i.at[row, rank].set(red.dst[order],
+                                                    mode="drop")
+            buckets_s = buckets_s.at[row, rank].set(red.src[order],
+                                                    mode="drop")
+            buckets_d = buckets_d.at[row, rank].set(red.dist[order],
+                                                    mode="drop")
             a2a = functools.partial(
                 jax.lax.all_to_all,
                 axis_name=axes if len(axes) > 1 else axes[0],
                 split_axis=0, concat_axis=0, tiled=True)
-            red_all = P.Requests(
+            return P.Requests(
                 dst=a2a(buckets_i).reshape(-1),
                 src=a2a(buckets_s).reshape(-1),
                 dist=a2a(buckets_d).reshape(-1),
             )
 
+        def stage(red_all):
+            local_red = _filter_to_local(red_all, row0, n_loc)
+            return P.group_requests(local_red, n_loc, cfg.cap,
+                                    drop_self=False)
+
+        if comm == "allgather":
+            staged_i, staged_d = stage(gather_all(redirect))
+        else:
+            # reverse rounds take the exact exchange whatever `comm` is:
+            # their requests pile onto the shards holding popular
+            # neighbours, which the update-sized buckets would drop
+            staged_i, staged_d = jax.lax.cond(
+                reverse, lambda red: stage(gather_all(red)),
+                lambda red: stage(bucket_a2a(red)), redirect)
+
         # survivors stay aligned in their shard (perf iteration g1):
         # only redirects go through the grouped-request path
         surv_ids = jnp.where(killed, -1, ids_loc)
         surv_dists = jnp.where(killed, jnp.inf, dists_loc)
-        local_red = _filter_to_local(red_all, row0, n_loc)
-        staged_i, staged_d = P.group_requests(local_red, n_loc, cfg.cap,
-                                              drop_self=False)
         ids2 = jnp.concatenate([surv_ids, staged_i], axis=-1)
         d2 = jnp.concatenate([surv_dists, staged_d], axis=-1)
         return ops.topr_merge(ids2, d2, r)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         round_body, mesh=mesh,
-        in_specs=(rspec, vspec, vspec, rspec),
+        in_specs=(rspec, vspec, vspec, rspec, rspec),
         out_specs=(vspec, vspec),
         check_vma=False,
     )
 
-    def build_round(x, pool: P.Pool, key) -> P.Pool:
-        ids, dists = sharded(x, pool.ids, pool.dists, key)
+    def build_round(x, pool: P.Pool, key, reverse=False) -> P.Pool:
+        ids, dists = sharded(x, pool.ids, pool.dists, key,
+                             jnp.asarray(reverse))
         return P.Pool(ids, dists)
 
     return build_round
@@ -199,21 +239,23 @@ def sharded_build_graph(
     vshard = NamedSharding(mesh, PSpec(tuple(axes)))
     rshard = NamedSharding(mesh, PSpec())
 
-    x = jax.device_put(x, rshard)
     k_init, k_rounds = jax.random.split(key)
-    pool = P.init_random(k_init, x, cfg.s, cfg.r)
+    # init on one device: its Pallas kernels cannot be auto-partitioned
+    # over a mesh (Mosaic calls run per device only inside a shard_map)
+    one = jax.sharding.SingleDeviceSharding(mesh.devices.flat[0])
+    pool = P.init_random(k_init, jax.device_put(x, one), cfg.s, cfg.r)
+    x = jax.device_put(x, rshard)
     pool = P.Pool(jax.device_put(pool.ids, vshard),
                   jax.device_put(pool.dists, vshard))
 
     round_fn = jax.jit(make_sharded_builder(mesh, axes, cfg, comm=comm))
-    rev_fn = jax.jit(functools.partial(_sharded_reverse, mesh, tuple(axes), cfg))
 
     for t1 in range(cfg.t1):
         for t2 in range(cfg.t2):
             k = jax.random.fold_in(jax.random.fold_in(k_rounds, t1), t2)
-            pool = round_fn(x, pool, k)
+            pool = round_fn(x, pool, k, False)
         if t1 != cfg.t1 - 1:
-            pool = rev_fn(pool)
+            pool = round_fn(x, pool, k_rounds, True)
     return pool
 
 
@@ -268,7 +310,7 @@ def _sharded_search_fn(mesh: Mesh, axes: tuple, k: int, ef: int,
     n_extra = 2 * quantized + has_rescore + has_valid + has_map
     in_specs = ((rspec, rspec, qspec, rspec) + (rspec,) * n_extra
                 + ((rspec, qspec) if has_filter else ()))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=SearchResult(qspec, qspec, qspec),
@@ -301,7 +343,8 @@ def distributed_search(
     graph are replicated; each shard runs the unmodified `core.search.search`
     on its query slice, so results are bitwise-identical to the single-device
     search for any shard count (no cross-shard state exists).  Queries are
-    padded to a multiple of the shard count and the pad rows sliced off.
+    padded so each shard's slice is a multiple of `search.Q_ALIGN` rows
+    (`search.align_queries`) and the pad rows sliced off.
 
     `x` may be a VectorStore (the precision ladder): the traversal tier
     replicates at its compact storage width — bf16 halves and int8 quarters
@@ -360,14 +403,7 @@ def distributed_search(
         ef_run, k_run, of_run = ef, k, 4
 
     q_in = queries  # pre-pad queries, for the host-side re-rank
-    qn = queries.shape[0]
-    pad = (-qn) % n_shards
-    if pad:
-        queries = jnp.concatenate(
-            [queries, jnp.broadcast_to(queries[:1], (pad, queries.shape[1]))])
-        if fwords is not None:  # the pad rows' predicates ride along
-            fwords = jnp.concatenate(
-                [fwords, jnp.broadcast_to(fwords[:1], (pad, fwords.shape[1]))])
+    queries, fwords, qn = align_queries(queries, fwords, n_shards)
 
     xd, xs, xo = VS.parts(x)
     quantized = xs is not None
@@ -395,9 +431,10 @@ def distributed_search(
     if filter is not None:
         extra += (jax.device_put(vwords, rep),
                   jax.device_put(fwords, qsharding))
-    res = sharded(xd, graph_ids, queries, entry, *extra)
-    if pad:
-        res = SearchResult(res.ids[:qn], res.dists[:qn], res.n_expanded[:qn])
+    # entry is replicated like x: a medoid computed from committed arrays
+    # lives on one device and would not join the mesh by itself
+    res = sharded(xd, graph_ids, queries, jax.device_put(entry, rep), *extra)
+    res = unpad(res, qn)
     if host:
         rv = rescore.gather(res.ids)                       # (Q, ef, D)
         out_ids, out_dists = _rescore_merge(
@@ -454,7 +491,7 @@ def _corpus_search_fn(mesh: Mesh, axes: tuple, n: int, k: int, ef: int,
                 + (sspec, rspec) * has_valid
                 + (sspec,) * has_map
                 + (sspec, rspec, rspec) * has_filter)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=SearchResult(rspec, rspec, rspec),
@@ -563,52 +600,10 @@ def sharded_apply_requests(
         d2 = jnp.concatenate([dists_loc, staged_d], axis=-1)
         return ops.topr_merge(ids2, d2, r)
 
-    ids, dists = jax.jit(shard_map(
+    ids, dists = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(vspec, vspec, rspec, rspec, rspec),
         out_specs=(vspec, vspec),
         check_vma=False,
     ))(pool.ids, pool.dists, req.dst, req.src, req.dist)
-    return P.Pool(ids, dists)
-
-
-def _sharded_reverse(mesh, axes, cfg: GRNNDConfig, pool: P.Pool) -> P.Pool:
-    """Reverse-edge sampling with cross-shard routing (all-gather exchange)."""
-    vspec = PSpec(axes)
-
-    def body(ids_loc, dists_loc):
-        n_loc, r = ids_loc.shape
-        sidx = jnp.int32(0)
-        for a in axes:
-            sidx = sidx * mesh.shape[a] + jax.lax.axis_index(a)
-        row0 = sidx * n_loc
-
-        rows = row0 + jnp.broadcast_to(
-            jnp.arange(n_loc, dtype=jnp.int32)[:, None], (n_loc, r))
-        deg = jnp.sum(ids_loc >= 0, axis=-1)[:, None]
-        take = jnp.ceil(cfg.rho * deg).astype(jnp.int32)
-        slot = jnp.broadcast_to(jnp.arange(r, dtype=jnp.int32)[None], (n_loc, r))
-        sel = (slot < take) & (ids_loc >= 0)
-
-        req = P.Requests(
-            dst=jnp.where(sel, ids_loc, -1).reshape(-1),
-            src=rows.reshape(-1),
-            dist=dists_loc.reshape(-1),
-        )
-        req_all = P.Requests(
-            dst=jax.lax.all_gather(req.dst, axes, tiled=True),
-            src=jax.lax.all_gather(req.src, axes, tiled=True),
-            dist=jax.lax.all_gather(req.dist, axes, tiled=True),
-        )
-        local = _filter_to_local(req_all, row0, n_loc)
-        staged_i, staged_d = P.group_requests(local, n_loc, cfg.cap,
-                                              drop_self=False)
-        ids2 = jnp.concatenate([ids_loc, staged_i], axis=-1)
-        d2 = jnp.concatenate([dists_loc, staged_d], axis=-1)
-        return ops.topr_merge(ids2, d2, r)
-
-    ids, dists = shard_map(
-        body, mesh=mesh, in_specs=(vspec, vspec), out_specs=(vspec, vspec),
-        check_vma=False,
-    )(pool.ids, pool.dists)
     return P.Pool(ids, dists)

@@ -163,14 +163,22 @@ def _sorted_requests_chunk(x, ids_c, dists_c, rows_c, key, cfg: GRNNDConfig):
 # ---------------------------------------------------------------------------
 
 def _chunked(pool: P.Pool, key, cfg: GRNNDConfig):
-    """Yield the (ids, dists, key) chunking plan, or None for one-shot."""
+    """Yield the (ids, dists, key) chunking plan, or None for one-shot.
+
+    A ragged last chunk (n % chunk != 0) is padded with empty vertices
+    (ids -1, dists +inf): they sample no valid pair, so they issue no
+    request and kill nothing, and callers slice them off at row n.
+    """
     n, r = pool.ids.shape
     chunk = cfg.chunk_size
-    if chunk is None or n % chunk != 0 or chunk >= n:
+    if chunk is None or chunk >= n:
         return None
-    n_chunks = n // chunk
-    return (pool.ids.reshape(n_chunks, chunk, r),
-            pool.dists.reshape(n_chunks, chunk, r),
+    n_chunks = -(-n // chunk)
+    pad = ((0, n_chunks * chunk - n), (0, 0))
+    return (jnp.pad(pool.ids, pad, constant_values=-1)
+            .reshape(n_chunks, chunk, r),
+            jnp.pad(pool.dists, pad, constant_values=jnp.inf)
+            .reshape(n_chunks, chunk, r),
             jax.random.split(key, n_chunks))
 
 
@@ -186,8 +194,8 @@ def _round_pair_matrices(x, pool: P.Pool, key, cfg: GRNNDConfig):
         lambda a: _pair_matrices_chunk(x, a[0], a[1], a[2], cfg),
         (ids_ch, dists_ch, keys))
     p = dst.shape[-1]
-    return (dst.reshape(n, p), src.reshape(n, p), dij.reshape(n, p),
-            killed.reshape(n, r))
+    return (dst.reshape(-1, p)[:n], src.reshape(-1, p)[:n],
+            dij.reshape(-1, p)[:n], killed.reshape(-1, r)[:n])
 
 
 def _round_requests(x, pool: P.Pool, key, cfg: GRNNDConfig):
@@ -199,15 +207,44 @@ def _round_requests(x, pool: P.Pool, key, cfg: GRNNDConfig):
         return _sorted_requests_chunk(x, pool.ids, pool.dists, rows, key, cfg)
 
     ids_ch, dists_ch, keys = plan
-    chunk = ids_ch.shape[1]
-    rows_ch = jnp.arange(n, dtype=jnp.int32).reshape(-1, chunk)
+    n_chunks, chunk = ids_ch.shape[:2]
+    rows_ch = jnp.arange(n_chunks * chunk, dtype=jnp.int32).reshape(-1, chunk)
     red, killed = jax.lax.map(
         lambda a: _sorted_requests_chunk(x, a[0], a[1], a[2], a[3], cfg),
         (ids_ch, dists_ch, rows_ch, keys))
+    # pad rows are empty, so their requests are all inactive (dst -1)
     redirect = P.Requests(
         dst=red.dst.reshape(-1), src=red.src.reshape(-1),
         dist=red.dist.reshape(-1))
-    return redirect, killed.reshape(n, r)
+    return redirect, killed.reshape(-1, r)[:n]
+
+
+def _update_requests(x, pool: P.Pool, key, cfg: GRNNDConfig):
+    """One round's redirects as (N, W) dst/src/dist matrices + the (N, R)
+    kill mask.  The disordered path passes the fused kernel's (N, P)
+    matrices straight through; the sorted ablations' flat requests are
+    vertex-major, so they fold to (N, R) rows (a chunk plan's pad rows
+    come last and are all inactive)."""
+    n, r = pool.ids.shape
+    if cfg.order == "disordered":
+        return _round_pair_matrices(x, pool, key, cfg)
+    redirect, killed = _round_requests(x, pool, key, cfg)
+
+    def rows(a):
+        return a.reshape(-1, r)[:n]
+
+    return rows(redirect.dst), rows(redirect.src), rows(redirect.dist), killed
+
+
+def _apply_requests(pool: P.Pool, dst, src, dist, killed,
+                    cfg: GRNNDConfig) -> P.Pool:
+    """Stage (N, W) requests and merge them with the surviving slots."""
+    n = pool.ids.shape[0]
+    staged_i, staged_d = P.stage_request_matrix(dst, src, dist, n, cfg.cap)
+    if killed is not None:
+        pool = P.Pool(jnp.where(killed, -1, pool.ids),
+                      jnp.where(killed, jnp.inf, pool.dists))
+    return P.merge_into(pool, staged_i, staged_d)
 
 
 def update_round(x, pool: P.Pool, key, cfg: GRNNDConfig) -> P.Pool:
@@ -220,23 +257,27 @@ def update_round(x, pool: P.Pool, key, cfg: GRNNDConfig) -> P.Pool:
 
     The disordered path consumes the fused kernel's (N, P) matrices
     directly (pools.stage_request_matrix) — no flat (N·P,) Requests
-    intermediate; the sorted ablations keep the Requests-tuple path.
+    intermediate.
     """
-    n, r = pool.ids.shape
-    if cfg.order == "disordered":
-        dst, src, dij, killed = _round_pair_matrices(x, pool, key, cfg)
-        staged_i, staged_d = P.stage_request_matrix(dst, src, dij, n, cfg.cap)
-    else:
-        redirect, killed = _round_requests(x, pool, key, cfg)
-        staged_i, staged_d = P.group_requests(redirect, n, cfg.cap)
-    surv_ids = jnp.where(killed, -1, pool.ids)
-    surv_dists = jnp.where(killed, jnp.inf, pool.dists)
-    return P.merge_into(P.Pool(surv_ids, surv_dists), staged_i, staged_d)
+    return _apply_requests(pool, *_update_requests(x, pool, key, cfg), cfg)
 
 
 # ---------------------------------------------------------------------------
 # Reverse edge sampling (§3.6)
 # ---------------------------------------------------------------------------
+
+def _reverse_requests(pool: P.Pool, rho):
+    """(N, R) requests inserting v into its top ρ·k neighbors' pools."""
+    n, r = pool.ids.shape
+    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, r))
+    deg = pool.degree()[:, None]                                  # (N, 1)
+    take = jnp.ceil(rho * deg).astype(jnp.int32)
+    slot = jnp.broadcast_to(jnp.arange(r, dtype=jnp.int32)[None, :], (n, r))
+    sel = (slot < take) & (pool.ids >= 0)
+    return (jnp.where(sel, pool.ids, -1),   # insert INTO neighbor
+            rows,                           # ... the owner vertex
+            pool.dists)                     # d symmetric
+
 
 def reverse_edge_round(pool: P.Pool, cfg: GRNNDConfig, rho=None) -> P.Pool:
     """Insert v into the pools of its top ρ·k neighbors (k = live degree).
@@ -245,24 +286,19 @@ def reverse_edge_round(pool: P.Pool, cfg: GRNNDConfig, rho=None) -> P.Pool:
     per-row prefix of ceil(ρ · degree) slots.
     """
     rho = cfg.rho if rho is None else rho
-    n, r = pool.ids.shape
-    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, r))
-    deg = pool.degree()[:, None]                                  # (N, 1)
-    take = jnp.ceil(rho * deg).astype(jnp.int32)
-    slot = jnp.broadcast_to(jnp.arange(r, dtype=jnp.int32)[None, :], (n, r))
-    sel = (slot < take) & (pool.ids >= 0)
-
-    req = P.Requests(
-        dst=jnp.where(sel, pool.ids, -1).reshape(-1),  # insert INTO neighbor
-        src=rows.reshape(-1),                          # ... the owner vertex
-        dist=pool.dists.reshape(-1),                   # d symmetric
-    )
-    return P.insert_requests(pool, req, cap=cfg.cap)
+    return _apply_requests(pool, *_reverse_requests(pool, rho), None, cfg)
 
 
 # ---------------------------------------------------------------------------
 # Full build (Alg. 3)
 # ---------------------------------------------------------------------------
+
+def _pad_cols(w, *mats):
+    """Pad (N, k) request matrices to width w with inactive requests."""
+    fills = (-1, -1, jnp.inf)
+    return tuple(jnp.pad(a, ((0, 0), (0, w - a.shape[1])), constant_values=f)
+                 for a, f in zip(mats, fills))
+
 
 @functools.partial(jax.jit, static_argnames=("cfg", "backend"))
 def _build_graph_impl(key: jax.Array, x: jnp.ndarray, cfg: GRNNDConfig,
@@ -272,27 +308,37 @@ def _build_graph_impl(key: jax.Array, x: jnp.ndarray, cfg: GRNNDConfig,
     `backend` is unused in the body but part of the jit key: the kernels
     dispatch on the global ops backend at TRACE time, so without it a
     cached executable from one backend would silently serve another.
+
+    The T1 blocks of T2 update rounds, with a reverse-edge round between
+    blocks, run as ONE loop whose body picks the round's requests and
+    then stages and merges them: the program holds a single copy of the
+    request staging (two sorts of N·P elements), which dominates its
+    compile time on a TPU.
     """
     del backend
     k_init, k_rounds = jax.random.split(key)
     pool = P.init_random(k_init, x, cfg.s, cfg.r)
+    n, r = pool.ids.shape
+    w = max(r, cfg.pairs_per_vertex if cfg.order == "disordered" else r)
 
-    def outer(t1_i, pool):
-        def inner(t2_i, carry):
-            pool = carry
-            k = jax.random.fold_in(jax.random.fold_in(k_rounds, t1_i), t2_i)
-            return update_round(x, pool, k, cfg)
+    def update(s, pool):
+        t1_i, t2_i = s // (t2 + 1), s % (t2 + 1)
+        k = jax.random.fold_in(jax.random.fold_in(k_rounds, t1_i), t2_i)
+        dst, src, dist, killed = _update_requests(x, pool, k, cfg)
+        return _pad_cols(w, dst, src, dist) + (killed,)
 
-        pool = jax.lax.fori_loop(0, t2, inner, pool)
-        pool = jax.lax.cond(
-            t1_i != t1 - 1,
-            lambda p: reverse_edge_round(p, cfg, rho=rho),
-            lambda p: p,
-            pool,
-        )
-        return pool
+    def reverse(s, pool):
+        return (_pad_cols(w, *_reverse_requests(pool, rho))
+                + (jnp.zeros((n, r), bool),))
 
-    return jax.lax.fori_loop(0, t1, outer, pool)
+    def step(s, pool):
+        reqs = jax.lax.cond(s % (t2 + 1) == t2, reverse, update, s, pool)
+        return _apply_requests(pool, *reqs, cfg)
+
+    # step s is update round s % (T2+1) of block s // (T2+1), or (at
+    # s % (T2+1) == T2) the reverse round after that block; the last
+    # block has none
+    return jax.lax.fori_loop(0, t1 * (t2 + 1) - 1, step, pool)
 
 
 def build_graph(key: jax.Array, x, cfg: GRNNDConfig) -> P.Pool:

@@ -25,7 +25,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import vecstore as VS
 from repro.kernels import ops
 
 
@@ -77,18 +76,14 @@ def init_random(key: jax.Array, x, s: int, r: int) -> Pool:
 def _owner_dists(x, owners: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     """d(x[owner], x[id]) for an (B, K) id matrix; invalid ids -> +inf.
 
-    Store-aware: rows are gathered dequantized (fp32), so the rowwise
-    kernel below sees the same values the fused build kernels dequantize
-    in VMEM.
+    One fused paired-distance call (`ops.gather_sqdist`): rows are read
+    at storage precision and dequantized in the kernel, the same values
+    the fused build kernels see, and no (B·K, D) gathered copy exists —
+    at N = 1M, S = 24 that copy alone would be 12 GB.
     """
     b, k = ids.shape
-    safe = jnp.clip(ids, 0)
-    xv = VS.take(x, owners)                                  # (B, D)
-    nv = VS.take(x, safe.reshape(-1)).reshape(b, k, -1)      # (B, K, D)
-    d = ops.rowwise_sqdist(
-        jnp.repeat(xv, k, axis=0).reshape(b * k, -1),
-        nv.reshape(b * k, -1),
-    ).reshape(b, k)
+    d = ops.gather_sqdist(x, jnp.repeat(owners, k),
+                          jnp.clip(ids, 0).reshape(-1)).reshape(b, k)
     return jnp.where(ids >= 0, d, jnp.inf)
 
 
@@ -138,53 +133,56 @@ def _stage(dst, src_in, dist_in, n: int, cap: int,
            drop_self: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Stage requests into per-destination buffers: -> ids/dists (N, cap).
 
-    Deterministic replacement for atomic concurrent insertion: requests are
-    ordered dist-minor / dst-major with two stable sorts, ranked within their
-    destination segment, and the first `cap` per destination scattered.
-    Self-inserts (dst == src; only meaningful when both live in the same id
-    space — see group_requests) and inactive requests are dropped.
+    Deterministic replacement for atomic concurrent insertion: repeated
+    (dst, src) requests are dropped, the rest ordered dst-major /
+    dist-minor, and each destination takes the first `cap` entries of its
+    run.  Self-inserts (dst == src; only meaningful when both live in the
+    same id space — see group_requests) and inactive requests are dropped.
+    Which copy of a repeated request survives, and the order among equal
+    distances, are fixed by the sort but not specified.
+
+    Built for the TPU compiler, whose time grows with the length of 1-D
+    scans and scatters and with the keys and operands of a sort (at
+    M = 12M: an associative_scan did not compile in 4 minutes, a scatter
+    took 20 s, a sort on an f32 key with a stable tie-break 157 s, the
+    same sort on int32 keys about a third of that): two 2-key int32
+    sorts carrying one payload each, a binary search per destination for
+    its run, and (N, cap) gathers.
     """
     if drop_self:
         dst = jnp.where(dst == src_in, -1, dst)
-
-    # dedup identical (dst, src) requests so duplicates cannot crowd out
-    # distinct candidates at the capacity rank below: sort src-minor /
-    # dst-major, invalidate repeats.
-    o1 = jnp.argsort(src_in, stable=True)
-    o2 = jnp.argsort(jnp.where(dst >= 0, dst, n)[o1], stable=True)
-    dperm = o1[o2]
-    dst_p, src_p = dst[dperm], src_in[dperm]
-    dup = jnp.concatenate([
-        jnp.array([False]),
-        (dst_p[1:] == dst_p[:-1]) & (src_p[1:] == src_p[:-1]) & (dst_p[1:] >= 0),
-    ])
-    dst = dst.at[dperm].set(jnp.where(dup, -1, dst_p))
-
-    dist = jnp.where(dst >= 0, dist_in, jnp.inf)
+    m = dst.shape[0]
+    if m == 0:
+        return (jnp.full((n, cap), -1, jnp.int32),
+                jnp.full((n, cap), jnp.inf, jnp.float32))
     dst_key = jnp.where(dst >= 0, dst, n)  # inactive sorts to the end
 
-    # stable composed sort: dist-minor then dst-major
-    order1 = jnp.argsort(dist, stable=True)
-    dst_s = dst_key[order1]
-    order2 = jnp.argsort(dst_s, stable=True)
-    perm = order1[order2]
+    # dedup identical (dst, src) requests so duplicates cannot crowd out
+    # distinct candidates at the capacity rank below: order by (dst, src),
+    # retire every repeat after the first
+    dst_p, src_p, idx_p = jax.lax.sort(
+        (dst_key, src_in, jnp.arange(m, dtype=jnp.int32)), num_keys=2)
+    dup = jnp.concatenate([
+        jnp.array([False]),
+        (dst_p[1:] == dst_p[:-1]) & (src_p[1:] == src_p[:-1]) & (dst_p[1:] < n),
+    ])
+    dst_p = jnp.where(dup, n, dst_p)
+    dist_p = jnp.where(dst_p < n, dist_in[idx_p], jnp.inf)
 
-    dst_s = dst_key[perm]
-    src_s = src_in[perm]
-    dist_s = dist[perm]
-
-    m = dst_s.shape[0]
-    idx = jnp.arange(m, dtype=jnp.int32)
-    is_start = jnp.concatenate([jnp.array([True]), dst_s[1:] != dst_s[:-1]])
-    seg_start = jax.lax.associative_scan(jnp.maximum, jnp.where(is_start, idx, 0))
-    rank = idx - seg_start
-
-    keep = (rank < cap) & (dst_s < n)
-    slot_dst = jnp.where(keep, dst_s, n)  # OOB rows dropped by mode="drop"
-    staged_ids = jnp.full((n, cap), -1, jnp.int32)
-    staged_dists = jnp.full((n, cap), jnp.inf, jnp.float32)
-    staged_ids = staged_ids.at[slot_dst, rank].set(src_s, mode="drop")
-    staged_dists = staged_dists.at[slot_dst, rank].set(dist_s, mode="drop")
+    # dst-major, dist-minor; a non-negative float's bits order like it
+    # (+ 0.0 folds a -0.0 into +0.0)
+    dist_bits = jax.lax.bitcast_convert_type(dist_p + 0.0, jnp.int32)
+    dst_s, bits_s, src_s = jax.lax.sort((dst_p, dist_bits, src_p),
+                                        num_keys=2)
+    dist_s = jax.lax.bitcast_convert_type(bits_s, jnp.float32)
+    # destination v's run is [start[v], start[v + 1]); take its first cap
+    start = jnp.searchsorted(dst_s, jnp.arange(n + 1, dtype=dst_s.dtype),
+                             side="left", method="scan").astype(jnp.int32)
+    pos = start[:n, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
+    ok = pos < start[1:, None]
+    pos = jnp.minimum(pos, m - 1)
+    staged_ids = jnp.where(ok, src_s[pos], -1)
+    staged_dists = jnp.where(ok, dist_s[pos], jnp.inf)
     return staged_ids, staged_dists
 
 

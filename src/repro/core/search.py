@@ -80,6 +80,35 @@ def medoid(x, valid: jnp.ndarray | None = None) -> jnp.ndarray:
 
 EF_CEILING = 512  # §9.3: past this, O(ef²) beam maintenance dominates
 
+# Query batches (each shard's, when queries are sharded) run padded to a
+# multiple of this: the TPU compiler takes minutes on a (Q, N) visited
+# array whose Q is not a whole sublane tile (Q = 251: 379 s, Q = 256:
+# 26 s, at N = 200k).  Pad rows repeat row 0;
+# per-query independence of the beam loop keeps them invisible.
+Q_ALIGN = 8
+
+
+def align_queries(queries, fwords=None, shards: int = 1):
+    """-> (queries, fwords, Q): both padded so that each of `shards`
+    equal row blocks (query-sharded search) is a multiple of Q_ALIGN."""
+    qn = queries.shape[0]
+    pad = (-qn) % (Q_ALIGN * shards)
+
+    def rep(a):
+        if a is None or pad == 0:
+            return a
+        return jnp.concatenate(
+            [a, jnp.broadcast_to(a[:1], (pad,) + tuple(a.shape[1:]))])
+
+    return rep(jnp.asarray(queries)), rep(fwords), qn
+
+
+def unpad(res: SearchResult, qn: int) -> SearchResult:
+    """The first qn rows of a result computed on padded queries."""
+    if res.ids.shape[0] == qn:
+        return res
+    return SearchResult(res.ids[:qn], res.dists[:qn], res.n_expanded[:qn])
+
 
 def overfetch_ef(n: int, k: int, selectivity: float, ef: int) -> int:
     """The §9.3 low-selectivity over-fetch policy, in one place (serving
@@ -424,6 +453,7 @@ def search(
         vwords = fwords = None  # labels alone is inert (no predicate given)
     if entry is None:
         entry = medoid(x, valid)
+    queries, fwords, qn = align_queries(queries, fwords)
     if visited == "dense":
         cap = 0  # unused; normalized so it never fragments the jit cache
     else:
@@ -442,9 +472,9 @@ def search(
         rv = rescore.gather(res.ids)                       # (Q, ef, D)
         out_ids, out_dists = _rescore_merge(
             res.ids, rv, jnp.asarray(queries, jnp.float32), ids_map, k=k)
-        return SearchResult(out_ids, out_dists, res.n_expanded)
-    return _search_impl(x, graph_ids, queries, entry, valid, rescore,
-                        vwords, fwords, ids_map,
-                        k=k, ef=ef, max_steps=max_steps,
-                        visited=visited, visited_cap=cap,
-                        backend=ops.effective_backend())
+        return unpad(SearchResult(out_ids, out_dists, res.n_expanded), qn)
+    return unpad(_search_impl(x, graph_ids, queries, entry, valid, rescore,
+                              vwords, fwords, ids_map,
+                              k=k, ef=ef, max_steps=max_steps,
+                              visited=visited, visited_cap=cap,
+                              backend=ops.effective_backend()), qn)

@@ -1,12 +1,12 @@
 """Dispatching wrappers for the Pallas kernels.
 
 Backend policy:
-  * "pallas"    — real pl.pallas_call lowering on TPU.  Off-TPU (CPU CI,
-                  local debugging) it degrades to interpret mode so the
-                  same code path still runs end-to-end.
-  * "interpret" — pallas_call(interpret=True): executes the kernel body in
-                  Python; used by tests on this CPU container to validate the
-                  kernels against the ref.py oracles.
+  * "pallas"    — real pl.pallas_call lowering; needs a TPU.  Selecting it
+                  anywhere else raises at the first kernel call: nothing
+                  quietly swaps in another backend.
+  * "interpret" — pallas_call(interpret=True): executes the kernel bodies
+                  in Python on any backend; runs only when asked for by
+                  name (the CPU parity suites and the CI interpret leg).
   * "ref"       — pure-jnp oracle; the fast path on CPU (XLA:CPU) and the
                   numerical ground truth.  "xla" is accepted as an alias.
   * "auto"      — pallas on TPU, ref elsewhere.
@@ -78,12 +78,17 @@ def backend(name: str):
 
 
 def effective_backend() -> str:
-    """The backend that will actually execute: real lowering only on TPU;
-    "pallas" elsewhere falls back to interpret so CPU CI exercises the
-    identical kernel bodies."""
+    """The backend that will actually execute.
+
+    "pallas" without a TPU raises instead of degrading: a run that asked
+    for the device kernels must not report numbers from another path.
+    """
     b = get_backend()
     if b == "pallas" and jax.default_backend() != "tpu":
-        return "interpret"
+        raise RuntimeError(
+            "kernel backend 'pallas' needs a TPU, but JAX's default backend "
+            f"is {jax.default_backend()!r}; select 'interpret' (the kernel "
+            "bodies in Python) or 'ref' (plain jnp) by name")
     return b
 
 

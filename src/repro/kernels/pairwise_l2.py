@@ -51,6 +51,7 @@ def _pairwise_kernel(x_ref, y_ref, *refs, xq: bool, yq: bool):
     xy = jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,   # full f32 on the MXU
         preferred_element_type=jnp.float32,
     )                                                              # (BM, BN)
     partial = xx + yy - 2.0 * xy

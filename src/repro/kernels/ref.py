@@ -7,6 +7,7 @@ orders of magnitude slower than XLA:CPU).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -61,7 +62,9 @@ def pairwise_sqdist_ref(
     y = dequant_rows(y, y_scale, y_offset)
     xx = jnp.sum(x * x, axis=-1, keepdims=True)  # (M, 1)
     yy = jnp.sum(y * y, axis=-1)[None, :]        # (1, N)
-    xy = x @ y.T                                  # (M, N)
+    # HIGHEST: on a TPU the default f32 matmul rounds its inputs to bf16,
+    # which would make the oracle (and brute-force ground truth) inexact
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)  # (M, N)
     return jnp.maximum(xx + yy - 2.0 * xy, 0.0)
 
 

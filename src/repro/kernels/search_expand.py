@@ -10,26 +10,26 @@ cell E; GGNN's fused gather-and-distance expansion is the GPU analogue).
 
 This kernel fuses the whole step.  Per query q it
 
-  1. gathers the R neighbor vectors of the selected vertex ONCE into a
-     VMEM scratch via index-dependent BlockSpecs over the scalar-prefetched
-     (clamped) neighbor ids — grid (Q, R), one row per step, the same
-     DMA-gather idiom as `rng_round.py`;
-  2. at the last row, computes all R query→neighbor squared distances
-     in-register (subtract-square-reduce, the `rowwise_sqdist_ref` order);
+  1. gathers the R neighbor vectors of the selected vertex ONCE into VMEM
+     (kernels/rows.py: one DMA per row at the clamped neighbor ids, for a
+     block of 8 queries per grid step, awaited together);
+  2. computes all R query->neighbor squared distances in-register
+     (subtract-square-reduce, the `rowwise_sqdist_ref` order);
   3. probes the query's open-addressed visited table (H int32 slots,
      identity-mod hash + linear probe window, DESIGN.md §6.1): the table
      is wrap-extended by PROBES slots outside the kernel, so each id's
-     probe window is one contiguous O(PROBES) dynamic slice — membership
-     work per id is independent of H — and emits (ids, dists, fresh-mask)
-     in one pass;
+     probe window is one contiguous run of PROBES slots; the kernel
+     compares each id against the whole (H + PROBES)-slot row under a
+     window mask (Mosaic has no dynamic lane slice), and emits (ids,
+     dists, fresh-mask) in one pass;
   4. applies the optional (N,) vertex-validity mask (the dynamic index's
-     tombstone mask, core/dynamic.py §DESIGN.md §7): each neighbor's
-     validity bit is DMA'd on the same per-row schedule as its vector, and
-     a dead neighbor is reported exactly like an empty graph slot
+     tombstone mask, core/dynamic.py §DESIGN.md §7): the wrapper gathers
+     each neighbor's validity bit with XLA (4 bytes next to a D-wide row)
+     and a dead neighbor is reported exactly like an empty graph slot
      (id -1, dist +inf, not fresh);
   5. evaluates the optional per-query label predicate (filtered search,
-     core/labels.py, DESIGN.md §9): the neighbor's (W,) packed label-bitset
-     words ride the same per-row DMA schedule, intersect with the query's
+     core/labels.py, DESIGN.md §9): the neighbors' (W,) packed label-bitset
+     words, gathered the same way, intersect with the query's
      allowed-bitset block, and emit an extra `allowed` output — ROUTE-
      THROUGH semantics, so ids/dists/fresh are untouched (the filtered-out
      neighbor stays traversable; only the result heap masks it).
@@ -59,9 +59,10 @@ nb_ref[q, rr] row indices near-sequential across the beam.
 Semantics match `ref.search_expand_ref` bitwise under a common jit context
 (tests/test_search_parity.py): probe positions follow the same
 identity-mod + linear-probe formula and the distance reduction follows the
-same subtract-square-reduce order.  As in `rng_round.py`, D is zero-padded
-to the 128-lane width for real lowering only; interpret mode — the bitwise
-parity harness — skips the pad to keep the fp32 reduction tree intact.
+same subtract-square-reduce order.  D is not padded: on the chip Mosaic's
+fp32 reduction tree over D may differ from XLA's, so distances agree with
+the oracle to ~1e-7 relative there rather than bitwise; interpret mode —
+the bitwise parity harness — runs the oracle's order.
 """
 from __future__ import annotations
 
@@ -70,100 +71,77 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import rows as RW
 # Single source of truth for the probe-window length (shared with the
 # oracle and the table-insert path in core/search.py).
 from repro.kernels.ref import HASH_PROBES
 
 
-def _search_expand_kernel(nbrs_pref, xrow_ref, *refs,
-                          r: int, h: int, probes: int, masked: bool,
-                          quantized: bool, filtered: bool):
-    """Grid: (Q, R). Step (q, rr) DMAs x[nbrs[q, rr]] (and, in the masked
-    variant, the neighbor's validity bit) into scratch row rr; the distance
-    + probe evaluation runs once per query on the final row.
+def _search_expand_kernel(*refs, n_src: int, r: int, h: int, probes: int,
+                          masked: bool, quantized: bool, filtered: bool):
+    """Grid: (Q / BLOCK,). One step gathers the neighbor rows of BLOCK
+    queries (kernels/rows.py) and evaluates distances + probes per query.
 
     `masked` is a trace-time flag: the static-index path (valid=None)
-    compiles WITHOUT the validity operand, scratch, or per-step DMA — the
-    dynamic feature costs the hot serving loop nothing unless it is used.
-    `quantized` (the precision ladder, DESIGN.md §8) likewise: the int8
-    variant carries (1, D) scale/offset operands and dequantizes each
-    DMA'd neighbor row as it lands in the fp32 scratch — the same
-    elementwise formula as `ref.dequant_rows` (bitwise oracle parity);
-    queries stay fp32.  `filtered` (filtered search, DESIGN.md §9) is the
-    same idiom again: the neighbor's (1, W) packed label-bitset words ride
-    the per-row DMA schedule, the query's (1, W) allowed-bitset words are
-    a per-query block, and the intersection test emits the extra `allowed`
+    compiles WITHOUT the validity operand — the dynamic feature costs the
+    hot serving loop nothing unless it is used.  `quantized` (the
+    precision ladder, DESIGN.md §8) likewise: the int8 variant carries
+    (1, D) scale/offset operands and dequantizes each gathered neighbor
+    row in VMEM — the same elementwise formula as `ref.dequant_rows`
+    (bitwise oracle parity); queries stay fp32.  `filtered` (filtered
+    search, DESIGN.md §9) is the same idiom again: the neighbors' (R, W)
+    packed label-bitset words and the query's (1, W) allowed-bitset words
+    are blocks, and the intersection test emits the extra `allowed`
     output — route-through semantics, so ids/dists/fresh are UNCHANGED by
     the predicate (the neighbor stays traversable either way).
     """
-    del nbrs_pref  # consumed by the index_maps
     it = iter(refs)
-    vrow_ref = next(it) if masked else None
-    lrow_ref = next(it) if filtered else None
+    src = [next(it) for _ in range(n_src)]
+    live_ref = next(it) if masked else None
+    lab_ref = next(it) if filtered else None
     scale_ref, offset_ref = ((next(it), next(it)) if quantized
                              else (None, None))
     q_ref, nbrs_ref, tab_ref = next(it), next(it), next(it)
     fw_ref = next(it) if filtered else None
     ids_ref, d_ref, fresh_ref = next(it), next(it), next(it)
     alw_ref = next(it) if filtered else None
-    vecs_ref = next(it)
-    live_ref = next(it) if masked else None
-    labw_ref = next(it) if filtered else None
-    rr = pl.program_id(1)
-    row = xrow_ref[...].astype(jnp.float32)
-    if quantized:
-        row = row * scale_ref[...] + offset_ref[...]
-    vecs_ref[pl.ds(rr, 1), :] = row
-    if masked:
-        live_ref[pl.ds(rr, 1), :] = vrow_ref[...]
-    if filtered:
-        labw_ref[pl.ds(rr, 1), :] = lrow_ref[...]
+    rows = RW.load_rows(src, list(it))
+    bq = q_ref.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (r, tab_ref.shape[1]), 1)
 
-    @pl.when(rr == r - 1)
-    def _evaluate():
-        vecs = vecs_ref[...]                          # (R, D) f32, VMEM
-        qv = q_ref[...].astype(jnp.float32)           # (1, D)
-        nbrs = nbrs_ref[...]                          # (1, R) int32
-        # wrap-extended table (1, H + PROBES): slot (v % H + l) % H of the
-        # H-slot table is slot (v % H) + l here, so each id's probe window
-        # is one contiguous O(PROBES) slice — work independent of H
-        tab = tab_ref[...]
-
+    for b in range(bq):                               # static unroll
+        vecs = rows[b]                                # (R, D) f32
+        if quantized:
+            vecs = vecs * scale_ref[...] + offset_ref[...]
+        qv = q_ref[b:b + 1, :].astype(jnp.float32)    # (1, D)
+        nb = nbrs_ref[b:b + 1, :]                     # (1, R) int32
         diff = vecs - qv                              # (R, D) broadcast
         d = jnp.sum(diff * diff, axis=1).reshape(1, r)
 
-        found = []
-        alive = []
-        allow = []
-        for j in range(r):                            # R is small: unrolled
-            v = nbrs[0, j]
-            base = jnp.clip(v, 0) % h
-            win = jax.lax.dynamic_slice(tab, (jnp.int32(0), base),
-                                        (1, probes))
-            found.append(jnp.any(win == v))
-            if masked:
-                alive.append(live_ref[j, 0] != 0)
-            if filtered:
-                # pure int32 bitwise intersection: bitwise-equal to the
-                # oracle's `any(vwords[id] & fwords[q])` on every rung
-                allow.append(jnp.any((labw_ref[j, :] & fw_ref[0, :]) != 0))
-        found = jnp.stack(found).reshape(1, r)
+        # wrap-extended table (1, H + PROBES): slot (v % H + l) % H of the
+        # H-slot table is slot (v % H) + l here, so each id's probe window
+        # is the contiguous run [base, base + PROBES) of this row
+        v = nb.reshape(r, 1)
+        base = jnp.clip(v, 0) % h
+        win = (pos >= base) & (pos < base + probes)
+        hit = win & (tab_ref[b:b + 1, :] == v)        # (R, H + PROBES)
+        found = jnp.max(hit.astype(jnp.int32), axis=1).reshape(1, r)
 
         # a tombstoned neighbor (valid[v] == 0) is indistinguishable from an
         # empty graph slot: never scored, never returned (ref.py contract)
-        ok = nbrs >= 0
+        ok = nb >= 0
         if masked:
-            ok = ok & jnp.stack(alive).reshape(1, r)
-        d = jnp.where(ok, d, jnp.inf)
-
-        ids_ref[...] = jnp.where(ok, nbrs, -1)
-        d_ref[...] = d
-        fresh_ref[...] = (ok & ~found).astype(jnp.int32)
+            ok = ok & (live_ref[b:b + 1, :] != 0)
+        ids_ref[b:b + 1, :] = jnp.where(ok, nb, -1)
+        d_ref[b:b + 1, :] = jnp.where(ok, d, jnp.inf)
+        fresh_ref[b:b + 1, :] = (ok & (found == 0)).astype(jnp.int32)
         if filtered:
-            alw_ref[...] = (ok & jnp.stack(allow).reshape(1, r)
-                            ).astype(jnp.int32)
+            # pure int32 bitwise intersection: bitwise-equal to the
+            # oracle's `any(vwords[id] & fwords[q])` on every rung
+            inter = (lab_ref[b] & fw_ref[b:b + 1, :]) != 0   # (R, W)
+            allow = jnp.max(inter.astype(jnp.int32), axis=1).reshape(1, r)
+            alw_ref[b:b + 1, :] = (ok & (allow != 0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -183,7 +161,7 @@ def search_expand_pallas(
     """Fused expansion step over a (Q, R) neighbor-id batch.
 
     Args:
-      x:       (N, D) dataset (stays in HBM; rows are DMA'd on demand;
+      x:       (N, D) dataset (stays in HBM; rows are gathered on demand;
                fp32/bf16/int8 storage per the precision ladder).
       queries: (Q, D) query vectors (always fp32 — only the stored dataset
                side rides the ladder).
@@ -194,15 +172,13 @@ def search_expand_pallas(
                (core/layout.py) — the kernel is width-agnostic.
       table:   (Q, H) int32 open-addressed visited table, -1 = empty slot.
       valid:   optional (N,) bool/int32 vertex-validity mask (tombstones,
-               core/dynamic.py).  Stays in HBM next to x; each neighbor's
-               bit rides the same per-row DMA schedule as its vector, so
-               the mask probe adds no extra pass.  None = all live.
+               core/dynamic.py); each neighbor's bit is gathered beside
+               its row.  None = all live.
       scale/offset: optional (D,) per-dim dequant of the stored x rows,
-               fused into the row DMA (None = float storage).
+               fused into the row load (None = float storage).
       vwords/fwords: optional filtered-search predicate (core/labels.py):
                (N, W) packed per-vertex label words + (Q, W) per-query
-               allowed words.  The neighbor's words ride the same per-row
-               DMA schedule as its vector/validity bit; both or neither.
+               allowed words; both or neither.
 
     Returns (ids (Q,R) i32, dists (Q,R) f32, fresh (Q,R) bool) — identical
     to `ref.search_expand_ref`; with the filter operands, a fourth element
@@ -216,82 +192,63 @@ def search_expand_pallas(
     filtered = fwords is not None
     assert filtered == (vwords is not None), \
         "vwords and fwords must be given together"
-    nbrs_safe = jnp.clip(nbrs.astype(jnp.int32), 0, n - 1)
+    bq = RW.BLOCK
+    # pad the batch to whole blocks with inactive queries (all ids -1)
+    nbrs_p = RW.pad_rows(nbrs.astype(jnp.int32), bq, -1)
+    qp = RW.pad_rows(queries, bq, 0.0)
+    nbrs_safe = jnp.clip(nbrs_p, 0, n - 1)
     # wrap-extend the table so every (mod H) probe window is contiguous:
     # ext[base + l] == table[(base + l) % H] for base < H, l < PROBES
     # (tiled, not a single concat, so H < PROBES also wraps correctly)
     reps = 1 + -(-HASH_PROBES // h)
-    tab_ext = jnp.tile(table.astype(jnp.int32),
+    tab_ext = jnp.tile(RW.pad_rows(table.astype(jnp.int32), bq, -1),
                        (1, reps))[:, :h + HASH_PROBES]
     he = h + HASH_PROBES
+    src_ops, src_specs, scratch = RW.row_source(x, nbrs_safe)
 
-    # Lane-align D for the real TPU lowering only (see module docstring).
-    # scale/offset pad with ZEROS, so padded columns of a quantized x
-    # dequant to exactly 0 and contribute nothing to any distance.
-    pad_d = 0 if interpret else (-d) % 128
-    xp = jnp.pad(x, ((0, 0), (0, pad_d))) if pad_d else x
-    qp = jnp.pad(queries, ((0, 0), (0, pad_d))) if pad_d else queries
-    dp = d + pad_d
+    def blk(w):
+        return pl.BlockSpec((bq, w), lambda i: (i, 0))
 
-    # the masked variant adds one (1, 1) validity block riding the same
-    # nb_ref[q, rr] index map as the x-row gather, plus its (R, 1) scratch
-    mask_specs = [pl.BlockSpec((1, 1), lambda q, rr, nb_ref:
-                               (nb_ref[q, rr], 0))] if masked else []
-    mask_scratch = [pltpu.VMEM((r, 1), jnp.int32)] if masked else []
-    mask_ops = ((valid.astype(jnp.int32).reshape(n, 1),) if masked else ())
-
-    # the filtered variant: the neighbor's (1, W) label words ride the same
-    # per-row DMA, the query's (1, W) allowed words are a per-query block
-    w = vwords.shape[1] if filtered else 0
-    lab_specs = [pl.BlockSpec((1, w), lambda q, rr, nb_ref:
-                              (nb_ref[q, rr], 0))] if filtered else []
-    lab_scratch = [pltpu.VMEM((r, w), jnp.int32)] if filtered else []
-    lab_ops = ((vwords.astype(jnp.int32),) if filtered else ())
-    fw_specs = [pl.BlockSpec((1, w), lambda q, rr, nb_ref:
-                             (q, 0))] if filtered else []
-    fw_ops = ((fwords.astype(jnp.int32),) if filtered else ())
-    alw_shape = [jax.ShapeDtypeStruct((qn, r), jnp.int32)] if filtered else []
-    alw_specs = [pl.BlockSpec((1, r), lambda q, rr, nb_ref:
-                              (q, 0))] if filtered else []
+    # per-neighbor validity bits and label words: XLA gathers of a few
+    # bytes per neighbor, handed to the kernel as ordinary blocks
+    mask_ops, mask_specs = (), []
+    if masked:
+        mask_ops = (valid.astype(jnp.int32)[nbrs_safe],)
+        mask_specs = [blk(r)]
+    lab_ops, lab_specs, fw_ops, fw_specs = (), [], (), []
+    alw_shape, alw_specs = [], []
+    if filtered:
+        w = vwords.shape[1]
+        lab_ops = (vwords.astype(jnp.int32)[nbrs_safe],)
+        lab_specs = [pl.BlockSpec((bq, r, w), lambda i: (i, 0, 0))]
+        fw_ops = (RW.pad_rows(fwords.astype(jnp.int32), bq, 0),)
+        fw_specs = [blk(w)]
+        alw_shape = [jax.ShapeDtypeStruct(nbrs_p.shape, jnp.int32)]
+        alw_specs = [blk(r)]
 
     q_ops, q_specs = (), []
     if quantized:
-        q_ops = tuple(
-            jnp.pad(v.astype(jnp.float32).reshape(1, d), ((0, 0), (0, pad_d)))
-            for v in (scale, offset))
-        q_specs = [pl.BlockSpec((1, dp), lambda q, rr, nb_ref: (0, 0))] * 2
+        q_ops = tuple(v.astype(jnp.float32).reshape(1, d)
+                      for v in (scale, offset))
+        q_specs = [pl.BlockSpec((1, d), lambda i: (0, 0))] * 2
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,               # nbrs_safe lands as index operand
-        grid=(qn, r),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda q, rr, nb_ref: (nb_ref[q, rr], 0)),
-        ] + mask_specs + lab_specs + q_specs + [
-            pl.BlockSpec((1, dp), lambda q, rr, nb_ref: (q, 0)),
-            pl.BlockSpec((1, r), lambda q, rr, nb_ref: (q, 0)),
-            pl.BlockSpec((1, he), lambda q, rr, nb_ref: (q, 0)),
-        ] + fw_specs,
-        out_specs=[
-            pl.BlockSpec((1, r), lambda q, rr, nb_ref: (q, 0)),
-            pl.BlockSpec((1, r), lambda q, rr, nb_ref: (q, 0)),
-            pl.BlockSpec((1, r), lambda q, rr, nb_ref: (q, 0)),
-        ] + alw_specs,
-        scratch_shapes=([pltpu.VMEM((r, dp), jnp.float32)] + mask_scratch
-                        + lab_scratch),
-    )
     out = pl.pallas_call(
-        functools.partial(_search_expand_kernel, r=r, h=h,
-                          probes=HASH_PROBES, masked=masked,
+        functools.partial(_search_expand_kernel, n_src=len(src_ops), r=r,
+                          h=h, probes=HASH_PROBES, masked=masked,
                           quantized=quantized, filtered=filtered),
-        grid_spec=grid_spec,
+        grid=(nbrs_p.shape[0] // bq,),
+        in_specs=(src_specs + mask_specs + lab_specs + q_specs
+                  + [blk(d), blk(r), blk(he)] + fw_specs),
+        out_specs=[blk(r), blk(r), blk(r)] + alw_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((qn, r), jnp.int32),
-            jax.ShapeDtypeStruct((qn, r), jnp.float32),
-            jax.ShapeDtypeStruct((qn, r), jnp.int32),
+            jax.ShapeDtypeStruct(nbrs_p.shape, jnp.int32),
+            jax.ShapeDtypeStruct(nbrs_p.shape, jnp.float32),
+            jax.ShapeDtypeStruct(nbrs_p.shape, jnp.int32),
         ] + alw_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(nbrs_safe, xp, *mask_ops, *lab_ops, *q_ops, qp,
-      nbrs.astype(jnp.int32), tab_ext, *fw_ops)
+    )(*src_ops, *mask_ops, *lab_ops, *q_ops, qp, nbrs_p, tab_ext, *fw_ops)
+    out = [o[:qn] for o in out]
     if filtered:
         ids, dists, fresh, allowed = out
         return ids, dists, fresh.astype(bool), allowed.astype(bool)

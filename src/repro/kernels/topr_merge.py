@@ -38,12 +38,16 @@ def _topr_merge_kernel(ids_ref, dists_ref, oi_ref, od_ref, *, r: int):
     dists = jnp.where(dup, jnp.inf, dists)
 
     # --- R selection rounds: extract first-min, mask it out ---
+    lane = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
     out_ids = []
     out_dists = []
     for _ in range(r):
         minv = jnp.min(dists, axis=-1, keepdims=True)            # (BR, 1)
-        is_min = dists == minv
-        first = is_min & (jnp.cumsum(is_min.astype(jnp.int32), axis=-1) == 1)
+        # first minimum: the lowest lane holding the min (no cumsum —
+        # Mosaic has no lowering for it)
+        at = jnp.min(jnp.where(dists == minv, lane, w), axis=-1,
+                     keepdims=True)
+        first = lane == at
         sel_id = jnp.sum(jnp.where(first, ids, 0), axis=-1)      # (BR,)
         valid = jnp.isfinite(minv[:, 0])
         out_ids.append(jnp.where(valid, sel_id, -1))

@@ -17,6 +17,8 @@ import numpy as np
 from repro.configs.grnnd_paper import DATASETS
 from repro.core import build_graph, sharded_build_graph
 from repro.data import synthetic
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -27,6 +29,7 @@ def main():
     ap.add_argument("--sharded", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     ds = DATASETS[args.dataset]
     preset = {"sift": "sift-like", "deep": "deep-like",
@@ -36,7 +39,7 @@ def main():
     t0 = time.perf_counter()
     if args.sharded:
         devs = len(jax.devices())
-        mesh = jax.make_mesh((devs,), ("data",))
+        mesh = make_mesh((devs,), ("data",))
         pool = sharded_build_graph(mesh, ("data",),
                                    jax.random.PRNGKey(args.seed + 1), x,
                                    ds.build)

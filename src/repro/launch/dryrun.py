@@ -19,7 +19,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs import list_archs
 from repro.configs.base import SHAPES
 from repro.launch import specs as SPEC
@@ -86,7 +85,7 @@ def _compile_stats(fn, args, mesh) -> dict:
         compiled = lowered.compile()
         t2 = time.time()
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis_dict(compiled)
+    cost = dict(compiled.cost_analysis())
     hlo = compiled.as_text()
     return {
         "lower_s": round(t1 - t0, 2),

@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction: every mesh of the program is built by `make_mesh`.
 
 NOTE: importing this module never touches jax device state; meshes are built
 inside functions only (the dry-run forces 512 host devices *before* any jax
@@ -8,6 +8,23 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """A mesh over the first prod(shape) devices, every axis `Auto`.
+
+    jax.make_mesh defaults to `AxisType.Explicit` axes, under which any
+    gather whose output sharding JAX cannot infer — the query-padding
+    slice of `distributed_search` among them — raises ShardingTypeError.
+    The shard_map executors of this program place data themselves, so
+    their meshes are Auto: the compiler propagates shardings as before.
+    """
+    shape, axes = tuple(shape), tuple(axes)
+    n = int(np.prod(shape))
+    devices = jax.devices()[:n] if devices is None else devices
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,13 +49,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — "
             "run under dryrun.py (XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512) or on real hardware")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
-
-
-def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for tests on a handful of forced host devices."""
-    n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 # TPU v5e hardware constants for the roofline model (per chip)

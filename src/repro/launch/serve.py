@@ -7,22 +7,25 @@
         [--engine --requests 256 --offered-qps 500 --mix-k 5,10]
 
 `--backend` selects the kernel path of the fused expansion step
-(`kernels/search_expand.py`; off-TPU "pallas" degrades to interpret mode).
+(`kernels/search_expand.py`; "pallas" needs a TPU, "interpret" runs the
+same kernel bodies in Python anywhere).
 `--visited hashed` swaps the dense (Q, N) visited bitmask for the O(Q·H)
 per-query open-addressed table — the memory-flat serving configuration
 (DESIGN.md §6).  `--shards K` shards the query batch over the first K
 devices via `core.distributed.distributed_search` (bitwise-identical to
-the single-device search; on a CPU box force host devices first with
-XLA_FLAGS=--xla_force_host_platform_device_count=K).
+the single-device search).  On a TPU host K counts its chips (1 or 4 on a
+v5e host); on a CPU box JAX sees one device unless host devices are
+forced (XLA_FLAGS=--xla_force_host_platform_device_count=K), which is for
+tests only.
 
 `--corpus-shards S` shards the CORPUS instead (core/corpus_shard.py,
 DESIGN.md §11): each shard owns 1/S of the vectors, graph rows, labels,
 and rescore tier — the layout that breaks the single-device memory
 ceiling on N.  Results are bitwise-identical to the replicated search for
-any S (the tests/test_corpus_shard.py invariance tier).  With at least S
-devices the shards map one-per-device over a mesh; with fewer, the
-in-process reference executor runs the identical math (useful for
-validation — the memory win needs real devices).  Mutually exclusive
+any S (the tests/test_corpus_shard.py invariance tier).  The shards map
+one-per-device over a mesh, so S may not exceed the device count: the
+run errors rather than fall back to an in-process executor that would
+hold every shard on one device.  Mutually exclusive
 with `--shards` (one sharding axis per process; compose them via a 2-D
 mesh in a custom launcher) and `--mutable`.
 
@@ -93,6 +96,8 @@ from repro.core.pools import Pool
 from repro.core.search import medoid, overfetch_ef, search
 from repro.data import synthetic
 from repro.kernels import ops
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -113,13 +118,13 @@ def main():
                     help="hashed-table slots per query "
                          "(default: core.search.default_visited_cap(ef))")
     ap.add_argument("--shards", type=int, default=0,
-                    help="shard query batches over this many devices "
-                         "(0 = single-device search)")
+                    help="shard query batches over this many devices, at "
+                         "most the chips JAX sees (0 = single-device "
+                         "search)")
     ap.add_argument("--corpus-shards", type=int, default=0,
                     help="shard the CORPUS over this many partitions "
-                         "(core/corpus_shard.py; 0 = replicated).  One "
-                         "shard per device when enough devices exist, "
-                         "else the bitwise-identical in-process reference")
+                         "(core/corpus_shard.py; 0 = replicated), one "
+                         "shard per device; at most the device count")
     ap.add_argument("--precision", default="fp32",
                     choices=["fp32", "bf16", "int8"],
                     help="traversal-tier vector storage (DESIGN.md §8); "
@@ -194,14 +199,18 @@ def main():
                     help="queries between churn events in the trace (only "
                          "with --engine --mutable)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.visited_cap is not None and args.visited != "hashed":
         ap.error("--visited-cap only applies with --visited hashed "
                  "(dense mode would silently ignore it)")
-    if args.shards > len(jax.devices()):
-        ap.error(f"--shards {args.shards} exceeds the {len(jax.devices())} "
-                 "available device(s); on a CPU box force host devices with "
-                 f"XLA_FLAGS=--xla_force_host_platform_device_count={args.shards}")
+    n_dev = len(jax.devices())
+    if args.shards > n_dev or args.corpus_shards > n_dev:
+        flag = "--shards" if args.shards > n_dev else "--corpus-shards"
+        ap.error(f"{flag} {max(args.shards, args.corpus_shards)} exceeds "
+                 f"the {n_dev} {jax.devices()[0].platform} device(s) JAX "
+                 "sees; one shard runs per device, so run on a host with "
+                 "that many chips (a TPU v5e host has 1 or 4)")
     if args.shards > 0 and args.mutable:
         ap.error("--mutable currently serves single-device (the mutation "
                  "path is not query-sharded); drop --shards")
@@ -258,7 +267,7 @@ def main():
 
     mesh = None
     if args.shards > 0:
-        mesh = jax.make_mesh((args.shards,), ("data",),
+        mesh = make_mesh((args.shards,), ("data",),
                              devices=jax.devices()[:args.shards])
         # replicate the index across the mesh ONCE; the per-batch
         # device_put inside distributed_search then no-ops on x/ids
@@ -506,10 +515,7 @@ def _static_setup(args, x, ids):
         cs_idx = CS.shard(xt, ids, args.corpus_shards, rescore=rescore,
                           labels=words, ids_map=ids_map, entry=entry,
                           tier=args.tier)
-        if args.corpus_shards <= len(jax.devices()):
-            cs_mesh = jax.make_mesh(
-                (args.corpus_shards,), ("data",),
-                devices=jax.devices()[:args.corpus_shards])
+        cs_mesh = make_mesh((args.corpus_shards,), ("data",))
     elif args.tier == "host" and rescore is not None:
         # host-cold placement (§13): wrap AFTER the layout pass so the
         # pinned tier holds the permuted rows the internal ids index

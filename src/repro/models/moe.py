@@ -113,7 +113,6 @@ def _moe_block_ep(params, cfg: ArchConfig, x: jnp.ndarray, hints):
     """
     from jax.sharding import PartitionSpec as PSpec
 
-    from repro.compat import shard_map
 
     b, s, d = x.shape
     e = cfg.n_experts
@@ -147,7 +146,7 @@ def _moe_block_ep(params, cfg: ArchConfig, x: jnp.ndarray, hints):
         return y, lb, jax.lax.pmean(drop, m_ax)
 
     xt = x.reshape(b * s, d)
-    y, lb, drop = shard_map(
+    y, lb, drop = jax.shard_map(
         body, mesh=hints.mesh,
         in_specs=(tspec, PSpec(), espec, espec, espec),
         out_specs=(tspec, PSpec(), PSpec()),

@@ -233,3 +233,43 @@ class TestSearch:
         r_lo = recall.recall_at_k(search(x, pool.ids, q, k=10, ef=16).ids, gt)
         r_hi = recall.recall_at_k(search(x, pool.ids, q, k=10, ef=96).ids, gt)
         assert r_hi >= r_lo
+
+
+# ---------------------------------------------------------------------------
+# synthetic corpora
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,m", [(1000, 4), (999, 8), (130, 32)])
+def test_subspace_clusters_lie_on_m_flats(n, m):
+    """latent_dim=m: every cluster is an affine m-flat, sizes near n/C."""
+    c, d = 8, 48
+    key = jax.random.PRNGKey(n + m)
+    x = np.asarray(synthetic.vector_dataset(key, n, d, n_clusters=c,
+                                            latent_dim=m), np.float64)
+    assert x.shape == (n, d) and np.isfinite(x).all()
+    np.testing.assert_array_equal(
+        x, np.asarray(synthetic.vector_dataset(key, n, d, n_clusters=c,
+                                               latent_dim=m)))
+    centers = np.asarray(jax.random.normal(jax.random.split(key, 3)[0],
+                                           (c, d)), np.float64)
+    # each point's nearest center: the points of a cluster, less their
+    # center, span exactly m dimensions
+    own = np.argmin(((x[:, None] - centers[None]) ** 2).sum(-1), 1)
+    sizes = np.bincount(own, minlength=c)
+    assert sizes.sum() == n and sizes.max() <= -(-n // c)
+    for j in range(c):
+        if sizes[j] > m:
+            sv = np.linalg.svd(x[own == j] - centers[j], compute_uv=False)
+            assert np.sum(sv > 1e-4 * sv[0]) == m, (j, sv[:m + 2])
+
+
+def test_presets_keep_isotropic_clusters():
+    """Only "sift1m-like" is a subspace preset; the others are unchanged."""
+    key = jax.random.PRNGKey(3)
+    np.testing.assert_array_equal(
+        synthetic.make_preset(key, "sift-like", 300),
+        synthetic.vector_dataset(key, 300, 128, n_clusters=128))
+    x = synthetic.make_preset(key, "sift1m-like", 300)
+    assert x.shape == (300, 128)
+    assert not np.array_equal(
+        x, synthetic.vector_dataset(key, 300, 128, n_clusters=128))

@@ -52,6 +52,7 @@ from repro.core import vecstore as VS
 from repro.core.search import search
 from repro.data import synthetic
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from conftest import optional_hypothesis
 
 # every suite in the interpret CI leg carries this marker: the
@@ -286,7 +287,7 @@ def test_mesh_executor_single_device_and_cache_key(case):
     store = L.encode_labels(
         jax.random.randint(jax.random.PRNGKey(8), (N,), 0, 16), 16)
     fw = L.random_query_filters(jax.random.PRNGKey(9), NQ, 16, 0.3)
-    mesh = jax.make_mesh((1,), ("corp",))
+    mesh = make_mesh((1,), ("corp",))
     idx = CS.shard(x, pool.ids, 1, labels=store)
     got_u = idx.search(q, k=K, ef=EF, mesh=mesh, axes=("corp",))
     before = _corpus_search_fn.cache_info().currsize
@@ -392,6 +393,7 @@ _SLOW_SCRIPT = textwrap.dedent("""
     from repro.core.distributed import _corpus_search_fn
     from repro.core.search import search
     from repro.data import synthetic
+    from repro.launch.mesh import make_mesh
 
     N, NQ, K, EF = 300, 18, 10, 32
     x = synthetic.make_preset(jax.random.PRNGKey(0), "tiny", N)
@@ -410,7 +412,7 @@ _SLOW_SCRIPT = textwrap.dedent("""
 
     out = {}
     for s in (2, 4):
-        mesh = jax.make_mesh((s,), ("data",), devices=jax.devices()[:s])
+        mesh = make_mesh((s,), ("data",), devices=jax.devices()[:s])
         idx = CS.shard(x, pool.ids, s)
         out[f"fp32-S{s}"] = same(
             search(x, pool.ids, q, k=K, ef=EF),
@@ -435,7 +437,7 @@ _SLOW_SCRIPT = textwrap.dedent("""
             CS.shard_optimized(opt, s).search(q, k=K, ef=EF, mesh=mesh))
 
     # cache-key regression on the multi-device executor
-    mesh2 = jax.make_mesh((2,), ("ck",), devices=jax.devices()[:2])
+    mesh2 = make_mesh((2,), ("ck",), devices=jax.devices()[:2])
     idxf = CS.shard(x, pool.ids, 2, labels=store)
     _ = idxf.search(q, k=K, ef=EF, mesh=mesh2, axes=("ck",))
     before = _corpus_search_fn.cache_info().currsize
@@ -454,7 +456,7 @@ _SLOW_SCRIPT = textwrap.dedent("""
     dc = DynamicConfig(refine_rounds=1)
     plain = DynamicIndex(x[:260], pool_b := grnnd.build_graph(
         jax.random.PRNGKey(5), x[:260], cfg), dc)
-    mesh3 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    mesh3 = make_mesh((4,), ("data",), devices=jax.devices()[:4])
     routed = DynamicIndex(x[:260], pool_b, dc, mesh=mesh3)
     lp = plain.insert(x[260:])
     lr = routed.insert(x[260:])
@@ -465,7 +467,7 @@ _SLOW_SCRIPT = textwrap.dedent("""
         "pool_dists": np.array_equal(np.asarray(plain.pool.dists),
                                      np.asarray(routed.pool.dists)),
     }
-    m2 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
+    m2 = make_mesh((2,), ("data",), devices=jax.devices()[:2])
     out["dyn_mesh_search"] = same(
         routed.search(q, k=K, ef=EF),
         routed.corpus_search(q, 2, k=K, ef=EF, mesh=m2))

@@ -20,9 +20,12 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    import numpy as np
     from repro.core import grnnd, recall, distributed
+    from repro.core import pools as P
     from repro.core.search import search
     from repro.data import synthetic
+    from repro.launch.mesh import make_mesh
 
     key = jax.random.PRNGKey(0)
     x = synthetic.make_preset(key, "tiny", 2048)
@@ -31,7 +34,7 @@ _SCRIPT = textwrap.dedent("""
     gt = recall.brute_force_knn(x, q, 10)
 
     out = {}
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for comm in ("allgather", "a2a"):
         pool = distributed.sharded_build_graph(
             mesh, ("data",), jax.random.PRNGKey(1), x, cfg, comm=comm)
@@ -39,7 +42,7 @@ _SCRIPT = textwrap.dedent("""
         res = search(x, jnp.asarray(ids), q, k=10, ef=32)
         out[comm] = recall.recall_at_k(res.ids, gt)
 
-    mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh2 = make_mesh((2, 4), ("pod", "data"))
     pool = distributed.sharded_build_graph(
         mesh2, ("pod", "data"), jax.random.PRNGKey(1), x, cfg)
     res = search(x, jnp.asarray(jax.device_get(pool.ids)), q, k=10, ef=32)
@@ -49,6 +52,28 @@ _SCRIPT = textwrap.dedent("""
     pool1 = grnnd.build_graph(jax.random.PRNGKey(1), x, cfg)
     res1 = search(x, pool1.ids, q, k=10, ef=32)
     out["single"] = recall.recall_at_k(res1.ids, gt)
+
+    # one reverse-edge round on a skewed pool (every neighbour on shard 0):
+    # its requests overflow the a2a buckets, so the round must take the
+    # exact exchange and equal the all-gather round bit for bit
+    n_loc = 2048 // 8
+    keys = jax.random.split(jax.random.PRNGKey(3), 2048)
+    skew = jax.vmap(lambda k: jax.random.choice(
+        k, n_loc, (cfg.r,), replace=False))(keys).astype(jnp.int32)
+    sd = jnp.sum((x[:, None, :] - x[skew]) ** 2, -1)
+    order = jnp.argsort(sd, axis=-1)
+    skew_pool = P.Pool(jnp.take_along_axis(skew, order, 1),
+                       jnp.take_along_axis(sd, order, 1))
+    rev = {}
+    for comm in ("allgather", "a2a"):
+        fn = jax.jit(distributed.make_sharded_builder(
+            mesh, ("data",), cfg, comm=comm))
+        rev[comm] = np.asarray(
+            fn(x, skew_pool, jax.random.PRNGKey(4), True).ids)
+    out["reverse_equal"] = bool(np.array_equal(rev["a2a"], rev["allgather"]))
+    # shard 0's rows took in reverse edges from every shard
+    out["reverse_inserted"] = int(np.sum(
+        rev["allgather"][:n_loc] >= n_loc))
     print("RESULT" + json.dumps(out))
 """)
 
@@ -81,3 +106,8 @@ def test_multi_axis_mesh_build(dist_results):
 
 def test_sharded_parity_with_single_device(dist_results):
     assert dist_results["allgather"] >= dist_results["single"] - 0.05
+
+
+def test_a2a_reverse_round_equals_allgather(dist_results):
+    assert dist_results["reverse_inserted"] > 0
+    assert dist_results["reverse_equal"]

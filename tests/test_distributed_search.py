@@ -39,12 +39,13 @@ _SCRIPT = textwrap.dedent("""
     from repro.core.distributed import _sharded_search_fn
     from repro.core.search import search
     from repro.data import synthetic
+    from repro.launch.mesh import make_mesh
 
     x = synthetic.make_preset(jax.random.PRNGKey(0), "tiny", 600)
     q = synthetic.queries_from(jax.random.PRNGKey(1), x, 100)  # 100 % 8 != 0
     cfg = grnnd.GRNNDConfig(s=8, r=16, t1=2, t2=3, pairs_per_vertex=16)
     pool = grnnd.build_graph(jax.random.PRNGKey(2), x, cfg)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     store = L.encode_labels(
         jax.random.randint(jax.random.PRNGKey(3), (600,), 0, 30), 30)
     fw = L.random_query_filters(jax.random.PRNGKey(4), 100, 30, 0.2)
@@ -70,7 +71,7 @@ _SCRIPT = textwrap.dedent("""
     ref_u = search(x, pool.ids, q, k=10, ef=32)
     ref_f = search(x, pool.ids, q, k=10, ef=32, labels=store, filter=fw)
     for s in (1, 2, 4):
-        m = jax.make_mesh((s,), ("data",), devices=jax.devices()[:s])
+        m = make_mesh((s,), ("data",), devices=jax.devices()[:s])
         got_u = distributed.distributed_search(
             m, ("data",), x, pool.ids, q, k=10, ef=32)
         got_f = distributed.distributed_search(
@@ -82,7 +83,7 @@ _SCRIPT = textwrap.dedent("""
     # cache-key regression: unfiltered then filtered at IDENTICAL shapes
     # on a fresh mesh axis name -> the cache must add an entry (has_filter
     # is part of the key) and the filtered results must obey the predicate
-    m2 = jax.make_mesh((2,), ("ck",), devices=jax.devices()[:2])
+    m2 = make_mesh((2,), ("ck",), devices=jax.devices()[:2])
     _ = distributed.distributed_search(m2, ("ck",), x, pool.ids, q,
                                        k=10, ef=32)
     before = _sharded_search_fn.cache_info().currsize

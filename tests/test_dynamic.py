@@ -27,6 +27,7 @@ from repro.core.search import _table_insert, medoid, search
 from repro.data import synthetic
 from repro.kernels import ref
 from repro.kernels.search_expand import search_expand_pallas
+from repro.launch.mesh import make_mesh
 from conftest import optional_hypothesis
 
 given, settings, st = optional_hypothesis()
@@ -432,7 +433,7 @@ def test_optimize_layout_is_idempotent_bitwise(small_index, corpus):
 def test_sharded_apply_requests_matches_single_device(small_index):
     from repro.core.distributed import sharded_apply_requests
     x, pool = small_index
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kd, ks = jax.random.split(jax.random.PRNGKey(7))
     req = Requests(
         dst=jax.random.randint(kd, (64,), -1, 600),
@@ -464,6 +465,7 @@ def test_sharded_apply_requests_multi_shard_parity():
         from repro.core.distributed import sharded_apply_requests
         from repro.core.pools import Requests, insert_requests
         from repro.data import synthetic
+        from repro.launch.mesh import make_mesh
 
         x = synthetic.make_preset(jax.random.PRNGKey(0), "tiny", 256)
         cfg = grnnd.GRNNDConfig(s=6, r=8, t1=2, t2=2, pairs_per_vertex=8)
@@ -476,7 +478,7 @@ def test_sharded_apply_requests_multi_shard_parity():
                        dist=jnp.abs(jax.random.normal(
                            jax.random.PRNGKey(3), (200,))))
         want = insert_requests(pool, req)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         got = sharded_apply_requests(mesh, ("data",), pool, req)
         same = (np.array_equal(np.asarray(want.ids), np.asarray(got.ids))
                 and np.array_equal(np.asarray(want.dists),

@@ -39,6 +39,7 @@ from repro.core import grnnd, labels as L, layout as LY, recall
 from repro.core import vecstore as VS
 from repro.core.search import search
 from repro.data import synthetic
+from repro.launch.mesh import make_mesh
 from conftest import optional_hypothesis
 
 # every suite in the interpret CI leg carries this marker: the
@@ -293,7 +294,7 @@ def test_distributed_optimized_matches_and_keys_cache(case):
     from repro.core import distributed
     from repro.core.distributed import _sharded_search_fn
     x, q, pool = case
-    mesh = jax.make_mesh((1,), ("lay",))
+    mesh = make_mesh((1,), ("lay",))
     opt = LY.optimize(x, pool, order="bfs")
     want = opt.search(q, k=K, ef=EF)
     _ = distributed.distributed_search(mesh, ("lay",), opt.x, opt.graph_ids,
@@ -322,6 +323,7 @@ def test_distributed_optimized_shard_count_invariance():
         from repro.core import vecstore as VS
         from repro.core.search import search
         from repro.data import synthetic
+        from repro.launch.mesh import make_mesh
 
         x = synthetic.make_preset(jax.random.PRNGKey(0), "tiny", 300)
         q = synthetic.queries_from(jax.random.PRNGKey(1), x, 18)  # 18 % 4 != 0
@@ -336,7 +338,7 @@ def test_distributed_optimized_shard_count_invariance():
             opt = LY.optimize(vs, pool, order="bfs", rescore=rescore)
             single = opt.search(q, k=10, ef=32)
             for s in (1, 2, 4):
-                m = jax.make_mesh((s,), ("data",),
+                m = make_mesh((s,), ("data",),
                                   devices=jax.devices()[:s])
                 got = opt.distributed_search(m, ("data",), q, k=10, ef=32)
                 out[f"{prec}-shards{s}"] = {

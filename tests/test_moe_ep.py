@@ -23,6 +23,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs.base import ArchConfig
     from repro.models import moe as M
     from repro.distributed import hints as H
+    from repro.launch.mesh import make_mesh
 
     out = {}
     for ncfg, (e, k, shared) in {
@@ -38,7 +39,7 @@ _SCRIPT = textwrap.dedent("""
         params = M.init_moe_params(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         dense, aux_d = M.moe_block(params, cfg, x)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with H.use_hints(mesh), mesh:
             ep, aux_e = jax.jit(
                 lambda p, v: M.moe_block(p, cfg, v))(params, x)

@@ -129,6 +129,56 @@ def test_chunked_round_matches_unchunked_matrices():
                                       np.asarray(want[3]))
 
 
+def test_ragged_last_chunk_matches_oracle():
+    """n % chunk_size != 0: the last chunk is padded with empty vertices
+    (never a one-shot fallback), each real row sees exactly the pairs a
+    direct call on its chunk would, and the whole build through the
+    interpret kernels stays bitwise equal to the ref build."""
+    x = synthetic.vector_dataset(jax.random.PRNGKey(3), 70, 16, n_clusters=4)
+    cfg = grnnd.GRNNDConfig(s=6, r=8, t1=2, t2=2, pairs_per_vertex=8,
+                            chunk_size=32)
+    pool = pools.init_random(jax.random.PRNGKey(4), x, cfg.s, cfg.r)
+    key = jax.random.PRNGKey(5)
+    plan = grnnd._chunked(pool, key, cfg)
+    assert plan is not None and plan[0].shape == (3, 32, 8)
+    dst, _, _, kill = grnnd._round_pair_matrices(x, pool, key, cfg)
+    assert dst.shape == (70, 8) and kill.shape == (70, 8)
+    keys = jax.random.split(key, 3)
+    ids_pad = jnp.pad(pool.ids, ((0, 26), (0, 0)), constant_values=-1)
+    d_pad = jnp.pad(pool.dists, ((0, 26), (0, 0)), constant_values=jnp.inf)
+    want = grnnd._pair_matrices_chunk(x, ids_pad[64:], d_pad[64:], keys[2],
+                                      cfg)
+    np.testing.assert_array_equal(np.asarray(dst[64:]),
+                                  np.asarray(want[0][:6]))
+    assert bool(jnp.all(want[0][6:] == -1)) and not bool(jnp.any(want[3][6:]))
+
+    built = {}
+    for b in ("ref", "interpret"):
+        with ops.backend(b):
+            built[b] = grnnd.build_graph(jax.random.PRNGKey(6), x, cfg)
+    np.testing.assert_array_equal(np.asarray(built["ref"].ids),
+                                  np.asarray(built["interpret"].ids))
+    np.testing.assert_array_equal(np.asarray(built["ref"].dists),
+                                  np.asarray(built["interpret"].dists))
+
+
+def test_pallas_backend_raises_off_tpu(monkeypatch):
+    """'pallas' never degrades: off-TPU it raises; interpret runs only
+    when asked for by name."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    ids = jnp.array([[3, 1, -1]], jnp.int32)
+    d = jnp.array([[0.3, 0.1, jnp.inf]], jnp.float32)
+    with ops.backend("pallas"):
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ops.effective_backend()
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ops.topr_merge(ids, d, 2)
+    with ops.backend("interpret"):
+        assert ops.effective_backend() == "interpret"
+        got = ops.topr_merge(ids, d, 2)
+    np.testing.assert_array_equal(np.asarray(got[0]), [[1, 3]])
+
+
 def test_env_var_selects_backend(monkeypatch):
     """REPRO_KERNEL_BACKEND is honored at import time; 'xla' aliases 'ref'."""
     assert ops._normalize("xla") == "ref"

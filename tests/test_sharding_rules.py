@@ -16,8 +16,8 @@ def mesh16():
         pytest.skip("no devices")
     # single CPU device replicated into an abstract mesh is not allowed;
     # use AbstractMesh for pure spec logic
-    from repro.compat import abstract_mesh
-    return abstract_mesh((4, 4), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((4, 4), ("data", "model"))
 
 
 class TestParamSpecs:

@@ -125,11 +125,11 @@ class TestCompression:
             import jax, jax.numpy as jnp
             import numpy as np
             from jax.sharding import PartitionSpec as P
-            from repro.compat import shard_map
             from repro.distributed.compression import compressed_psum_mean
-            mesh = jax.make_mesh((4,), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("pod",))
             x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
-            f = shard_map(
+            f = jax.shard_map(
                 lambda v: compressed_psum_mean(v[0], "pod")[None],
                 mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
             got = np.asarray(f(x))

@@ -38,6 +38,7 @@ from repro.core import grnnd, labels as L, layout as LY
 from repro.core import vecstore as VS
 from repro.core.dynamic import DynamicConfig, DynamicIndex
 from repro.core.search import medoid, search
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.kernel_parity
 
@@ -191,7 +192,7 @@ def test_corpus_sharded_host_tier_mesh_executor(case):
     owner-combine, bitwise the reference executor."""
     x, q, pool = case
     vs = VS.encode(x, "int8")
-    mesh = jax.make_mesh((1,), ("corp",))
+    mesh = make_mesh((1,), ("corp",))
     host = CS.shard(vs, pool.ids, 1, rescore=x, tier="host")
     got = host.search(q, k=K, ef=EF, mesh=mesh, axes=("corp",))
     _assert_same(search(vs, pool.ids, q, k=K, ef=EF, rescore=x), got,
@@ -230,7 +231,7 @@ def test_distributed_search_host_tier_bitwise_equal(case, filtered):
     from repro.core.distributed import distributed_search
     x, q, pool = case
     vs = VS.encode(x, "int8")
-    mesh = jax.make_mesh((1,), ("q",))
+    mesh = make_mesh((1,), ("q",))
     kw = {}
     if filtered:
         store = L.encode_labels(
