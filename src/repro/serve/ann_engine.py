@@ -30,6 +30,12 @@ the vllm EngineCore split is the architectural exemplar):
   occupancy, per-bucket execution counts.  The clock is injectable and the
   worker is a three-method protocol, so every scheduling decision is
   deterministic and testable on CPU with a fake worker.
+* **spans** — while a profiler session captures, each step, its batch
+  forming, the worker's dispatch and read-back and the per-request fan-out
+  are `repro.obs` spans (`ann_engine.step`, `ann_engine.form`,
+  `worker.dispatch`, `worker.readback`, `ann_engine.fanout`), with the
+  batch's size, its bucket, its queue waits and per-row `n_expanded` as
+  counts.
 """
 
 from __future__ import annotations
@@ -40,9 +46,11 @@ import time
 from collections import deque
 from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import labels as L
 from repro.core.search import medoid, search
 
@@ -102,6 +110,14 @@ class QueryResult(NamedTuple):
 
 
 class EngineStats(NamedTuple):
+    """Counters of the window since `reset_stats()`.
+
+    `p50_ms`/`p99_ms` are the time a request spent in the engine, from
+    `submit()` to its batch's results.  Under open load that is not what
+    a user waits: a caller that submits only between steps delays
+    `submit()` while a batch runs, and that delay is not counted.
+    """
+
     n_completed: int
     n_rejected: int
     n_mutations: int  # individual vectors inserted/deleted, not requests
@@ -258,12 +274,17 @@ class AnnEngine:
         if self._mutations and (
             not self._queries or self._since_mut >= self.cfg.query_quantum
         ):
-            self._run_mutation()
-            return True
-        if self._queries:
-            self._run_query_batch()
-            return True
-        return False
+            kind = "mutation"
+        elif self._queries:
+            kind = "query"
+        else:
+            return False
+        with obs.span("ann_engine.step", kind=kind):
+            if kind == "mutation":
+                self._run_mutation()
+            else:
+                self._run_query_batch()
+        return True
 
     def run(self, max_steps: int | None = None) -> int:
         """Step until idle (or max_steps); returns the steps taken."""
@@ -286,47 +307,56 @@ class AnnEngine:
         self.log.append(("mutation", mut.kind, mut.n_items))
 
     def _run_query_batch(self) -> None:
-        head = self._queries[0]
-        key = (head.ef, head.fwords is not None)
-        group: list[QueryRequest] = []
-        rest: deque[QueryRequest] = deque()
-        while self._queries:
-            r = self._queries.popleft()
-            if len(group) < self.cfg.max_batch and (r.ef, r.fwords is not None) == key:
-                group.append(r)
-            else:
-                rest.append(r)
-        self._queries = rest
+        with obs.span("ann_engine.form") as counts:
+            t_form = None if counts is None else self.clock()
+            head = self._queries[0]
+            key = (head.ef, head.fwords is not None)
+            group: list[QueryRequest] = []
+            rest: deque[QueryRequest] = deque()
+            while self._queries:
+                r = self._queries.popleft()
+                if len(group) < self.cfg.max_batch and (r.ef, r.fwords is not None) == key:
+                    group.append(r)
+                else:
+                    rest.append(r)
+            self._queries = rest
 
-        ef, filtered = key
-        qb = bucket_q(len(group))
-        pad = qb - len(group)
-        # pad rows repeat the last real request: per-query independence
-        # (§12.2) makes them invisible to the real rows, and a duplicate of
-        # real work converges in the same number of beam steps
-        q = np.stack([r.vector for r in group] + [group[-1].vector] * pad)
-        fw = None
-        if filtered:
-            fw = np.stack([r.fwords for r in group] + [group[-1].fwords] * pad)
+            ef, filtered = key
+            qb = bucket_q(len(group))
+            pad = qb - len(group)
+            # pad rows repeat the last real request: per-query independence
+            # (§12.2) makes them invisible to the real rows, and a duplicate of
+            # real work converges in the same number of beam steps
+            q = np.stack([r.vector for r in group] + [group[-1].vector] * pad)
+            fw = None
+            if filtered:
+                fw = np.stack([r.fwords for r in group] + [group[-1].fwords] * pad)
+            if counts is not None:
+                counts.update(
+                    n_real=len(group),
+                    bucket=qb,
+                    wait_s=[t_form - r.t_submit for r in group],
+                )
         k_exec = min(self.cfg.k_cap, ef)
         ids, dists = self.worker.search_batch(q, k=k_exec, ef=ef, fwords=fw)
-        t = self.clock()
-        self._t_last_done = t
-        for i, r in enumerate(group):
-            self._results[r.rid] = QueryResult(
-                ids=np.asarray(ids)[i, : r.k],
-                dists=np.asarray(dists)[i, : r.k],
-                t_submit=r.t_submit,
-                t_done=t,
-            )
-            self._lat.append(t - r.t_submit)
-        self.n_completed += len(group)
-        self._occ.append(len(group) / qb)
-        bkey = (qb, ef, filtered)
-        self._buckets_seen[bkey] = self._buckets_seen.get(bkey, 0) + 1
-        self._bucket_runs[bkey] = self._bucket_runs.get(bkey, 0) + 1
-        self._since_mut += 1
-        self.log.append(("query", bkey, len(group)))
+        with obs.span("ann_engine.fanout"):
+            t = self.clock()
+            self._t_last_done = t
+            for i, r in enumerate(group):
+                self._results[r.rid] = QueryResult(
+                    ids=np.asarray(ids)[i, : r.k],
+                    dists=np.asarray(dists)[i, : r.k],
+                    t_submit=r.t_submit,
+                    t_done=t,
+                )
+                self._lat.append(t - r.t_submit)
+            self.n_completed += len(group)
+            self._occ.append(len(group) / qb)
+            bkey = (qb, ef, filtered)
+            self._buckets_seen[bkey] = self._buckets_seen.get(bkey, 0) + 1
+            self._bucket_runs[bkey] = self._bucket_runs.get(bkey, 0) + 1
+            self._since_mut += 1
+            self.log.append(("query", bkey, len(group)))
 
     # ----------------------------------------------------------------- stats
 
@@ -364,6 +394,23 @@ class AnnEngine:
 
 
 # ------------------------------------------------------------------- workers
+
+
+def _run_search(call):
+    """Run one worker search, `call()` -> `SearchResult`, and read its ids
+    and distances back to the host in one transfer: the span
+    `worker.dispatch` up to the return of the device arrays, then
+    `worker.readback`.  While a profiler session captures, the same
+    transfer also fetches the per-row `n_expanded` of the whole bucket,
+    padding included, as a count."""
+    with obs.span("worker.dispatch"):
+        res = call()
+    with obs.span("worker.readback") as counts:
+        if counts is None:
+            return jax.device_get((res.ids, res.dists))
+        ids, dists, n_expanded = jax.device_get((res.ids, res.dists, res.n_expanded))
+        counts["n_expanded"] = n_expanded.tolist()
+    return ids, dists
 
 
 class StaticWorker:
@@ -408,23 +455,24 @@ class StaticWorker:
             raise ValueError("filtered request against a worker built without labels")
         # overfetch=1: admission already applied the §9.3 policy, so the
         # compiled ef here equals a direct call's effective ef
-        res = search(
-            self.x,
-            self.graph_ids,
-            jnp.asarray(q),
-            k=k,
-            ef=ef,
-            entry=self.entry,
-            visited=self.visited,
-            visited_cap=self.visited_cap,
-            valid=self.valid,
-            rescore=self.rescore,
-            labels=self.vwords if filtered else None,
-            filter=jnp.asarray(fwords) if filtered else None,
-            overfetch=1,
-            ids_map=self.ids_map,
+        return _run_search(
+            lambda: search(
+                self.x,
+                self.graph_ids,
+                jnp.asarray(q),
+                k=k,
+                ef=ef,
+                entry=self.entry,
+                visited=self.visited,
+                visited_cap=self.visited_cap,
+                valid=self.valid,
+                rescore=self.rescore,
+                labels=self.vwords if filtered else None,
+                filter=jnp.asarray(fwords) if filtered else None,
+                overfetch=1,
+                ids_map=self.ids_map,
+            )
         )
-        return np.asarray(res.ids), np.asarray(res.dists)
 
     def apply_mutation(self, mut: MutationRequest) -> None:
         raise RuntimeError("StaticWorker serves a frozen index; use DynamicWorker")
@@ -441,16 +489,17 @@ class DynamicWorker:
         self.visited_cap = visited_cap
 
     def search_batch(self, q, *, k: int, ef: int, fwords=None):
-        res = self.index.search(
-            jnp.asarray(q),
-            k=k,
-            ef=ef,
-            visited=self.visited,
-            visited_cap=self.visited_cap,
-            filter=None if fwords is None else jnp.asarray(fwords),
-            overfetch=1,
+        return _run_search(
+            lambda: self.index.search(
+                jnp.asarray(q),
+                k=k,
+                ef=ef,
+                visited=self.visited,
+                visited_cap=self.visited_cap,
+                filter=None if fwords is None else jnp.asarray(fwords),
+                overfetch=1,
+            )
         )
-        return np.asarray(res.ids), np.asarray(res.dists)
 
     def apply_mutation(self, mut: MutationRequest) -> None:
         idx = self.index
@@ -477,17 +526,18 @@ class ShardedWorker:
         self.visited_cap = visited_cap
 
     def search_batch(self, q, *, k: int, ef: int, fwords=None):
-        res = self.index.search(
-            jnp.asarray(q),
-            k=k,
-            ef=ef,
-            visited=self.visited,
-            visited_cap=self.visited_cap,
-            filter=None if fwords is None else jnp.asarray(fwords),
-            overfetch=1,
-            mesh=self.mesh,
+        return _run_search(
+            lambda: self.index.search(
+                jnp.asarray(q),
+                k=k,
+                ef=ef,
+                visited=self.visited,
+                visited_cap=self.visited_cap,
+                filter=None if fwords is None else jnp.asarray(fwords),
+                overfetch=1,
+                mesh=self.mesh,
+            )
         )
-        return np.asarray(res.ids), np.asarray(res.dists)
 
     def apply_mutation(self, mut: MutationRequest) -> None:
         raise RuntimeError("ShardedWorker serves a frozen index; use DynamicWorker")

@@ -12,14 +12,21 @@ Two tiers in one module:
   visited, grouped+padded into pow2 buckets), and the dynamic path equals
   a twin DynamicIndex receiving the same mutations directly.
 
+The span tier checks what the engine records while a profiler session
+captures (`repro.obs`): the step, form and fan-out records with a fake
+worker, and the real worker's `n_expanded`.
+
 Runs in BOTH CI legs (kernel_parity marker): sizes stay interpret-safe.
 """
+
+import collections
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import grnnd
 from repro.core import labels as lab
 from repro.core import vecstore
@@ -345,3 +352,125 @@ class TestEngineParity:
             np.testing.assert_array_equal(res.ids, np.asarray(direct.ids)[i])
             np.testing.assert_array_equal(res.dists, np.asarray(direct.dists)[i])
         assert eng.stats().n_mutations == 12
+
+
+# ------------------------------------------------------------------ spans
+
+
+@pytest.fixture
+def capturing(monkeypatch):
+    """Record `repro.obs` spans into a fresh ring, as if a profiler
+    session captured."""
+    monkeypatch.setattr(obs, "_ring", collections.deque(maxlen=obs.RING))
+    monkeypatch.setattr(obs, "_capturing", lambda: True)
+
+
+class TestEngineSpans:
+    def test_step_form_and_fanout_records(self, capturing):
+        # submits at t=0,1,2; the batch starts at t=2.5; service 1s
+        eng, w, clk = fake_engine(max_batch=8, ef_menu=(48,))
+        for i in range(3):
+            eng.submit(vec(i), k=5, ef=48)
+            clk.advance(1.0)
+        clk.advance(-0.5)
+        assert eng.step()
+        (step,) = obs.records("ann_engine.step")
+        (form,) = obs.records("ann_engine.form")
+        (fanout,) = obs.records("ann_engine.fanout")
+        assert step.parent is None
+        assert step.counts == {"kind": "query"}
+        assert form.parent == step.id and fanout.parent == step.id
+        assert form.counts == {
+            "n_real": 3,
+            "bucket": 4,
+            "wait_s": [2.5, 1.5, 0.5],
+        }
+        assert form.t1 <= fanout.t0
+
+    def test_groups_split_into_one_record_per_batch(self, capturing):
+        eng, w, _ = fake_engine(max_batch=2, ef_menu=(32, 48))
+        for i, ef in enumerate((32, 48, 32, 32)):
+            eng.submit(vec(i), k=5, ef=ef)
+        assert eng.run() == 3
+        forms = obs.records("ann_engine.form")
+        # batches [0, 2] (ef 32), [1] (ef 48), [3] (ef 32); each call takes 1 s
+        assert [c[2] for c in w.calls] == [32, 48, 32]
+        assert [f.counts["wait_s"] for f in forms] == [[0.0, 0.0], [1.0], [2.0]]
+        assert [(f.counts["n_real"], f.counts["bucket"]) for f in forms] == [
+            (2, 2),
+            (1, 1),
+            (1, 1),
+        ]
+        steps = obs.records("ann_engine.step")
+        assert [f.parent for f in forms] == [s.id for s in steps]
+
+    def test_mutation_steps_are_recorded_by_kind(self, capturing):
+        eng, _, _ = fake_engine(max_batch=4, ef_menu=(48,))
+        eng.submit_delete_oldest(2)
+        eng.submit(vec(0), k=5, ef=48)
+        assert eng.run() == 2
+        assert not eng.step()  # an idle step is no scheduling decision
+        kinds = [s.counts["kind"] for s in obs.records("ann_engine.step")]
+        assert sorted(kinds) == ["mutation", "query"]
+        assert len(obs.records("ann_engine.form")) == 1
+
+    def test_nothing_is_recorded_without_a_profiler_session(self, monkeypatch):
+        monkeypatch.setattr(obs, "_ring", collections.deque(maxlen=obs.RING))
+        eng, _, _ = fake_engine(max_batch=4, ef_menu=(48,))
+        for i in range(3):
+            eng.submit(vec(i), k=5, ef=48)
+        eng.run()
+        assert obs.records() == []
+
+    def test_static_worker_records_the_n_expanded_of_the_padded_batch(
+        self, built, requests, capturing
+    ):
+        x, pool, _ = built
+        q, _, _ = requests
+        entry = medoid(x)
+        worker = StaticWorker(x, pool.ids, entry=entry)
+        eng = AnnEngine(worker, EngineConfig(ef_menu=(48,), max_batch=8))
+        for i in range(5):
+            eng.submit(q[i], k=10, ef=48)
+        eng.run()
+        (step,) = obs.records("ann_engine.step")
+        (dispatch,) = obs.records("worker.dispatch")
+        (readback,) = obs.records("worker.readback")
+        assert dispatch.parent == step.id and readback.parent == step.id
+        assert dispatch.t1 <= readback.t0
+        # the same padded batch the engine formed: 5 real rows, 3 repeats
+        padded = np.concatenate([q[:5], np.repeat(q[4:5], 3, axis=0)])
+        direct = search(x, pool.ids, jnp.asarray(padded), k=10, ef=48, entry=entry)
+        assert readback.counts["n_expanded"] == np.asarray(direct.n_expanded).tolist()
+        assert len(readback.counts["n_expanded"]) == 8
+
+    @pytest.mark.parametrize("kind", ["static", "dynamic"])
+    def test_recorded_read_back_returns_what_the_unrecorded_one_does(
+        self, built, requests, monkeypatch, kind
+    ):
+        # while capturing, the read-back fetches ids, distances and
+        # n_expanded in one transfer; the answers must not change
+        x, pool, _ = built
+        q, _, _ = requests
+
+        def answers():
+            if kind == "static":
+                worker = StaticWorker(x, pool.ids, entry=medoid(x))
+            else:
+                idx = DynamicIndex(x, Pool(pool.ids, pool.dists), DynamicConfig(refine_rounds=1))
+                worker = DynamicWorker(idx)
+            eng = AnnEngine(worker, EngineConfig(ef_menu=(48,), max_batch=8))
+            rids = [eng.submit(q[i], k=10, ef=48) for i in range(6)]
+            eng.run()
+            return [eng.take_result(r) for r in rids]
+
+        monkeypatch.setattr(obs, "_ring", collections.deque(maxlen=obs.RING))
+        plain = answers()
+        assert obs.records() == []
+        monkeypatch.setattr(obs, "_capturing", lambda: True)
+        recorded = answers()
+        (readback,) = obs.records("worker.readback")
+        assert len(readback.counts["n_expanded"]) == 8
+        for a, b in zip(plain, recorded):
+            np.testing.assert_array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.dists, b.dists)
