@@ -12,7 +12,7 @@ Query-path configuration (EXPERIMENTS.md §Perf cell E):
 `search_expand` kernel; "pallas" needs a TPU, and off-TPU "interpret"
 runs the same kernels as a correctness harness, so the dataset is capped
 and rows are labeled with the effective backend).  `--visited` selects the visited-set
-representation (dense (Q, N) bitmask vs the O(Q·H) hashed table).  Graph
+representation (dense bitmap vs the O(Q·H) hashed table).  Graph
 construction stays on the ambient default path: the graph under test is
 identical across query configurations, per the paper's protocol.
 

@@ -398,8 +398,9 @@ def _corpus_body(
         if filtered:
             allowed = _cor(al_parts, axes)
 
-        # visited-set logic runs replicated on GLOBAL ids — the same math
-        # the replicated search applies (dense: exact bitmask; hashed: the
+        # visited-set logic runs replicated on GLOBAL ids — the same sets
+        # the replicated search keeps (dense: exact, here a (Q, N) bool
+        # mask where the replicated kernel keeps a bitmap; hashed: the
         # kernel's probe formula via search._table_member)
         if visited == "dense":
             seen = vstate[qrows[:, None], jnp.clip(nbrs, 0)]

@@ -10,8 +10,10 @@ The production pieces (DESIGN.md §6):
   * the expansion step — gather the selected vertex's R neighbor vectors,
     compute query->neighbor distances, probe the visited set — is one fused
     op (`ops.search_expand`, kernels/search_expand.py) with a ref.py oracle;
-  * the visited set is selectable: `visited="dense"` keeps the exact (Q, N)
-    bitmask (right at reproduction scale), `visited="hashed"` replaces it
+  * the visited set is selectable: `visited="dense"` keeps an exact
+    bit-packed (Q, ⌈N/32⌉)-word bitmap (`ref.visited_bitmap`), which the
+    fused op probes and sets in the same pass, streaming N/8 bytes per
+    query a step; `visited="hashed"` replaces it
     with a fixed-size per-query open-addressed table of `visited_cap` int32
     slots, making search memory O(Q·H) independent of N.  Collisions and
     capacity misses only cause harmless re-expansions, never false skips;
@@ -49,7 +51,7 @@ import jax.numpy as jnp
 from repro.core import labels as L
 from repro.core import vecstore as VS
 from repro.kernels import ops
-from repro.kernels.ref import visited_probe_positions
+from repro.kernels.ref import visited_bitmap, visited_probe_positions
 
 
 class SearchResult(NamedTuple):
@@ -81,10 +83,11 @@ def medoid(x, valid: jnp.ndarray | None = None) -> jnp.ndarray:
 EF_CEILING = 512  # §9.3: past this, O(ef²) beam maintenance dominates
 
 # Query batches (each shard's, when queries are sharded) run padded to a
-# multiple of this: the TPU compiler takes minutes on a (Q, N) visited
-# array whose Q is not a whole sublane tile (Q = 251: 379 s, Q = 256:
-# 26 s, at N = 200k).  Pad rows repeat row 0;
-# per-query independence of the beam loop keeps them invisible.
+# multiple of this: the fused expansion kernel works on blocks of 8
+# queries, and a batch of whole blocks hands it the visited bitmap as it
+# is, where a ragged one would copy the bitmap to pad it every step.  Pad
+# rows repeat row 0; per-query independence of the beam loop keeps them
+# invisible.
 Q_ALIGN = 8
 
 
@@ -234,15 +237,13 @@ def _search_impl(
         res_dists = jnp.full((q, ef), jnp.inf, jnp.float32).at[:, 0].set(
             jnp.where(e_ok, d_entry, jnp.inf))
 
-    entry_col = jnp.broadcast_to(entry, (q, 1)).astype(jnp.int32)
-    if visited == "dense":
-        vstate = jnp.zeros((q, n), bool).at[:, entry].set(True)
-        # an empty 1-slot table turns the fused kernel's probe into a no-op
-        lookup = jnp.full((q, 1), -1, jnp.int32)
+    dense = visited == "dense"
+    if dense:
+        vstate = visited_bitmap(q, n, entry)
     else:
-        vstate = _table_insert(jnp.full((q, visited_cap), -1, jnp.int32),
-                               entry_col)
-        lookup = None
+        vstate = _table_insert(
+            jnp.full((q, visited_cap), -1, jnp.int32),
+            jnp.broadcast_to(entry, (q, 1)).astype(jnp.int32))
 
     def cond(state):
         frontier = (state[0] >= 0) & ~state[2]
@@ -254,27 +255,26 @@ def _search_impl(
         sel = jnp.argmin(frontier_d, axis=-1)                      # (Q,)
         active = jnp.isfinite(jnp.min(frontier_d, axis=-1))        # (Q,)
         sel_id = cand_ids[qrows, sel]
-        expanded = expanded.at[qrows, sel].set(True)
+        expanded = expanded | (jnp.arange(ef) == sel[:, None])
 
         nbrs = graph_ids[jnp.clip(sel_id, 0)]                      # (Q, R)
         nbrs = jnp.where(active[:, None] & (nbrs >= 0), nbrs, -1)
 
         # fused: gather neighbor vectors, query->neighbor distances, the
         # visited probe, the tombstone-validity probe, and (filtered) the
-        # label-predicate test in one pass (dense mode probes the empty
-        # dummy table and refines `fresh` with the exact bitmask below)
+        # label-predicate test in one pass; the dense bitmap is also set
+        # in that pass, the hashed table after it
         out = ops.search_expand(
-            x, queries, nbrs, vstate if lookup is None else lookup, valid,
-            vwords if filtered else None, fwords if filtered else None)
+            x, queries, nbrs, None if dense else vstate, valid,
+            vwords if filtered else None, fwords if filtered else None,
+            vstate if dense else None)
+        if dense:
+            *out, vstate = out
         if filtered:
             nbrs, dq, fresh, allowed = out
         else:
             nbrs, dq, fresh = out
-        if visited == "dense":
-            seen = vstate[qrows[:, None], jnp.clip(nbrs, 0)]
-            fresh = fresh & ~seen
-            vstate = vstate.at[qrows[:, None], jnp.clip(nbrs, 0)].max(fresh)
-        else:
+        if not dense:
             vstate = _table_insert(vstate, jnp.where(fresh, nbrs, -1))
 
         dq = jnp.where(fresh, dq, jnp.inf)
@@ -397,9 +397,10 @@ def search(
     `core.vecstore.VectorStore` (bf16 / int8 per the precision ladder,
     DESIGN.md §8) — the fused expansion kernel dequantizes rows on the fly.
 
-    `visited` selects the visited-set representation: "dense" (exact (Q, N)
-    bitmask) or "hashed" (per-query `visited_cap`-slot open-addressed table,
-    O(Q·H) memory independent of N — the serving configuration at scale).
+    `visited` selects the visited-set representation: "dense" (exact
+    bitmap, N/8 bytes per query) or "hashed" (per-query `visited_cap`-slot
+    open-addressed table, O(Q·H) memory independent of N — the choice once
+    the bitmap's memory is too large).
     `visited_cap` defaults to `default_visited_cap(ef)`.
 
     `valid` is the dynamic index's (N,) vertex-validity mask (tombstoned or

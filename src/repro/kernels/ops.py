@@ -124,25 +124,29 @@ def topr_merge(ids: jnp.ndarray, dists: jnp.ndarray, r: int):
 
 
 def search_expand(x, queries, nbrs, table, valid=None, vwords=None,
-                  fwords=None):
-    """Fused beam-search expansion step: (ids, dists, fresh[, allowed]).
+                  fwords=None, bitmap=None):
+    """Fused beam-search expansion step:
+    (ids, dists, fresh[, allowed][, bitmap]).
 
     See ref.search_expand_ref for semantics; the pallas path fuses the
-    neighbor-vector gather, query->neighbor distances, the visited-table
-    probe, and the optional tombstone-validity probe into one VMEM-resident
-    pass (kernels/search_expand.py).  `valid` is the dynamic index's (N,)
+    neighbor-vector gather, query->neighbor distances, the visited probe,
+    and the optional tombstone-validity probe into one VMEM-resident
+    pass (kernels/search_expand.py).  The visited set is either the
+    hashed `table` or, with `table=None`, the dense `bitmap`, which comes
+    back updated as the last output.  `valid` is the dynamic index's (N,)
     vertex-validity mask (None = all live, the static-index path).  `x`
     may be a VectorStore (fused dequant on the row DMA).  `vwords`/`fwords`
     are the optional filtered-search predicate (core/labels.py): packed
     (N, W) vertex label words + (Q, W) query allowed words; when given,
-    a fourth `allowed` output is appended (route-through semantics).
+    an `allowed` output is appended (route-through semantics).
     """
     xd, xs, xo = _parts(x)
     if get_backend() == "ref":
         return _ref.search_expand_ref(xd, queries, nbrs, table, valid,
-                                      xs, xo, vwords, fwords)
+                                      xs, xo, vwords, fwords, bitmap)
     return search_expand_pallas(xd, queries, nbrs, table, valid, xs, xo,
-                                vwords, fwords, interpret=_interpret())
+                                vwords, fwords, bitmap,
+                                interpret=_interpret())
 
 
 def rng_propagation_round(x, ids, dists, si, sj):

@@ -19,6 +19,8 @@ __all__ = [
     "gather_sqdist_ref",
     "dequant_rows",
     "visited_probe_positions",
+    "visited_bitmap",
+    "bitmap_slots",
     "HASH_PROBES",
 ]
 
@@ -159,6 +161,30 @@ def visited_probe_positions(ids: jnp.ndarray, h: int) -> jnp.ndarray:
             jnp.arange(HASH_PROBES, dtype=jnp.int32)) % h
 
 
+def bitmap_slots(ids: jnp.ndarray):
+    """-> (row, lane, bit) of each id in the dense visited bitmap.
+
+    Vertex v is bit `v & 31` of lane `(v >> 5) & 127` in row `v >> 12` of
+    its query's (rows, 128) int32 block (DESIGN.md §6): one row holds
+    4,096 vertices, one (8, 128) tile 32,768.  ids < 0 map to vertex 0;
+    callers mask them.
+    """
+    v = jnp.clip(ids.astype(jnp.int32), 0)
+    return v >> 12, (v >> 5) & 127, v & 31
+
+
+def visited_bitmap(q: int, n: int, entry) -> jnp.ndarray:
+    """(Q, rows, 128) int32 dense visited bitmap with only `entry` set.
+
+    rows covers N vertices in whole (8, 128) tiles (8·⌈N/32768⌉), so the
+    fused kernel reads one aligned tile per probed id.
+    """
+    rows = 8 * -(-n // 32768)
+    row, lane, bit = bitmap_slots(jnp.asarray(entry))
+    return jnp.zeros((q, rows, 128), jnp.int32).at[:, row, lane].set(
+        jnp.left_shift(jnp.int32(1), bit))
+
+
 def search_expand_ref(
     x: jnp.ndarray,
     queries: jnp.ndarray,
@@ -168,6 +194,7 @@ def search_expand_ref(
     scale=None, offset=None,
     vwords: jnp.ndarray | None = None,
     fwords: jnp.ndarray | None = None,
+    bitmap: jnp.ndarray | None = None,
 ):
     """One fused beam-search expansion step (see kernels/search_expand.py).
 
@@ -183,6 +210,7 @@ def search_expand_ref(
                rows keep their sentinels as a tail suffix, which changes
                nothing here (the mask is positionless).
       table:   (Q, H) int32 open-addressed visited table; -1 = empty slot.
+               None when `bitmap` is given.
       valid:   optional (N,) bool vertex-validity mask (the dynamic index's
                tombstone mask, core/dynamic.py).  A neighbor whose vertex is
                tombstoned is treated exactly like an empty graph slot: it is
@@ -200,6 +228,10 @@ def search_expand_ref(
                flagged in the extra `allowed` output, which the search
                uses to mask it out of the result heap.  Both or neither
                must be given.
+      bitmap:  the dense visited set in place of `table`: a (Q, rows, 128)
+               int32 bitmap (`visited_bitmap`).  Every id is probed before
+               any bit is set, so a duplicate id in a row is fresh in each
+               of its slots, as it is against an unchanged table.
 
     Returns (ids (Q,R) i32, dists (Q,R) f32, fresh (Q,R) bool): the
     neighbor ids (invalid/dead -> -1), exact squared query->neighbor
@@ -209,7 +241,8 @@ def search_expand_ref(
     fresh, which the deduplicating beam merge absorbs.  With the filter
     operands a fourth element `allowed (Q,R) bool` is appended: live AND
     `any(vwords[id] & fwords[q])` — pure int32 bitwise math, so kernel and
-    oracle agree bitwise on every precision rung.
+    oracle agree bitwise on every precision rung.  With `bitmap`, the
+    updated bitmap (the fresh ids' bits set) is appended last.
     """
     q, r = nbrs.shape
     ok = nbrs >= 0
@@ -221,17 +254,32 @@ def search_expand_ref(
     d = jnp.sum(diff * diff, axis=-1)
     d = jnp.where(ok, d, jnp.inf)
 
-    h = table.shape[1]
-    pos = visited_probe_positions(nbrs, h)                    # (Q, R, PL)
-    qrows = jnp.arange(q, dtype=jnp.int32)[:, None, None]
-    vals = table[qrows, pos]                                  # (Q, R, PL)
-    found = jnp.any(vals == nbrs[..., None], axis=-1)
-    out = (jnp.where(ok, nbrs, -1), d, ok & ~found)
-    if fwords is None:
-        return out
-    lw = vwords[jnp.clip(nbrs, 0)]                            # (Q, R, W)
-    allowed = ok & jnp.any((lw & fwords[:, None, :]) != 0, axis=-1)
-    return out + (allowed,)
+    if bitmap is None:
+        h = table.shape[1]
+        pos = visited_probe_positions(nbrs, h)                # (Q, R, PL)
+        qrows = jnp.arange(q, dtype=jnp.int32)[:, None, None]
+        vals = table[qrows, pos]                              # (Q, R, PL)
+        found = jnp.any(vals == nbrs[..., None], axis=-1)
+    else:
+        qrows = jnp.arange(q, dtype=jnp.int32)[:, None]
+        row, lane, bit = bitmap_slots(nbrs)
+        one = jnp.left_shift(jnp.int32(1), bit)
+        found = (bitmap[qrows, row, lane] & one) != 0
+    fresh = ok & ~found
+    out = (jnp.where(ok, nbrs, -1), d, fresh)
+    if fwords is not None:
+        lw = vwords[jnp.clip(nbrs, 0)]                        # (Q, R, W)
+        allowed = ok & jnp.any((lw & fwords[:, None, :]) != 0, axis=-1)
+        out = out + (allowed,)
+    if bitmap is not None:
+        # scatter-or of the fresh bits: a fresh bit is clear, so adding
+        # it once (first copy of a duplicated id only) is an OR
+        same = nbrs[:, :, None] == nbrs[:, None, :]
+        dup = jnp.any(same & jnp.tril(jnp.ones((r, r), bool), k=-1), axis=-1)
+        bitmap = bitmap.at[qrows, row, lane].add(
+            jnp.where(fresh & ~dup, one, 0))
+        out = out + (bitmap,)
+    return out
 
 
 def topr_merge_ref(

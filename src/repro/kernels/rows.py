@@ -62,13 +62,17 @@ def row_source(x: jnp.ndarray, ids: jnp.ndarray):
             [pltpu.VMEM((bb, k, d), jnp.float32)])
 
 
-def load_rows(src, scratch):
+def load_rows(src, scratch, during=None):
     """In-kernel: -> a (bb, K, D) fp32 ref holding this block's rows.
 
     `src`/`scratch` are the refs `row_source` set up (2 + 2 on the DMA
     path, 1 + 1 on the pre-gathered path).  All DMAs of the block are in
     flight together and share one semaphore; each wait consumes one row.
+    `during`, if given, is called while they are in flight (first, on the
+    pre-gathered path): work that needs no row hides their latency.
     """
+    if during is not None and len(src) == 1:
+        during()
     if len(src) == 1:
         # widen once into VMEM: the kernels then start from the same
         # materialized fp32 rows as the oracle's `dequant_rows`
@@ -92,5 +96,7 @@ def load_rows(src, scratch):
         return c
 
     jax.lax.fori_loop(0, bb * k, start, 0)
+    if during is not None:
+        during()
     jax.lax.fori_loop(0, bb * k, wait, 0)
     return buf

@@ -9,8 +9,8 @@
 `--backend` selects the kernel path of the fused expansion step
 (`kernels/search_expand.py`; "pallas" needs a TPU, "interpret" runs the
 same kernel bodies in Python anywhere).
-`--visited hashed` swaps the dense (Q, N) visited bitmask for the O(Q·H)
-per-query open-addressed table — the memory-flat serving configuration
+`--visited hashed` swaps the dense visited bitmap (N/8 bytes per query)
+for the O(Q·H) per-query open-addressed table — the memory-flat serving configuration
 (DESIGN.md §6).  `--shards K` shards the query batch over the first K
 devices via `core.distributed.distributed_search` (bitwise-identical to
 the single-device search).  On a TPU host K counts its chips (1 or 4 on a

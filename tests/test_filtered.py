@@ -199,12 +199,12 @@ def test_unfiltered_trace_has_no_filter_operand():
                                             entry=jnp.int32(0), labels=v,
                                             filter=f, overfetch=2)
         )(x, g, q, vw, fw)
-    ep2 = [e for e in _pallas_eqns(sp.jaxpr)
-           if len(e.outvars) in (3, 4)]       # the expand kernels
-    ef2 = [e for e in _pallas_eqns(sf.jaxpr) if len(e.outvars) in (3, 4)]
+    # the expand kernels (dense visited: the bitmap is their last output)
+    ep2 = [e for e in _pallas_eqns(sp.jaxpr) if len(e.outvars) >= 3]
+    ef2 = [e for e in _pallas_eqns(sf.jaxpr) if len(e.outvars) >= 3]
     assert ep2 and ef2
-    assert all(len(e.outvars) == 3 for e in ep2)
-    assert all(len(e.outvars) == 4 for e in ef2)
+    assert all(len(e.outvars) == 4 for e in ep2)
+    assert all(len(e.outvars) == 5 for e in ef2)
     assert all(len(e.invars) == len(ep2[0].invars) + 2 for e in ef2)
 
 

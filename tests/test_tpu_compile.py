@@ -81,6 +81,21 @@ def test_search_expand_compiles(chip, filtered):
     _compile(chip, fn, *shapes)
 
 
+@pytest.mark.parametrize("q", [Q, 64], ids=["q1024", "q64"])
+def test_search_expand_bitmap_compiles(chip, q):
+    """The dense visited bitmap streams through VMEM a block of 8
+    queries at a time, (8, ⌈N/32768⌉, 8, 128) int32 in and out, each
+    double-buffered: at N = 1M that is 4 x 1 MB, inside the 16 MiB the
+    compiler scopes for a kernel on a v5e without a vmem limit."""
+    rows = 8 * -(-N // 32768)
+    block = 8 * rows * 128 * 4
+    assert 4 * block + 8 * R * D * 4 < 16 * 2**20
+    _compile(chip, lambda x, qv, nb, bm: search_expand_pallas(
+        x, qv, nb, None, bitmap=bm),
+        ((N, D), jnp.float32), ((q, D), jnp.float32), ((q, R), jnp.int32),
+        ((q, rows, 128), jnp.int32))
+
+
 def test_gather_l2_compiles(chip):
     m = 24 * CHUNK  # one chunk's S=24 initial neighbors
     _compile(chip, gather_sqdist_pallas, ((N, D), jnp.float32),
